@@ -17,18 +17,19 @@ Rows:
 * ``route/crossover`` -- dense-vs-kernel wall clock over the server sweep
   (times as machine-dependent ``*_s`` fields; the crossover point itself
   as a string note) plus the ``speedup`` at the largest dense-feasible K.
-* ``route/servers1e3..1e6`` -- per-K simulation metrics from the kernel
+* ``route/servers1e3..1e5`` -- per-K simulation metrics from the kernel
   path: messages, AQ sup vs the Theorem 2.3 bound, sup queue gap.  These
   are exact integers from a fixed stream (deterministic ties +
   deterministic service), so the 2% trajectory gate pins them tight.
 * ``route/ssc/*`` -- the diffusion-limit prediction at mean-field scale:
-  sup_t max_ij |Q_i - Q_j| stays O(1) as n grows through {1e3..1e6}, so
+  sup_t max_ij |Q_i - Q_j| stays O(1) as n grows through {1e3..1e5}, so
   the sqrt(n)-scaled gap collapses (Theorem 7.3 read through the SSC
   lens); ``route/ssc/summary`` gates the monotone-collapse claim.
 
-Quick mode sweeps n in {1e3, 1e4, 1e5} on a 1000-slot horizon; full mode
-lengthens the horizon and adds the kernel-only n = 1e6 point (the dense
-backend is not run there -- that scale is the kernel's reason to exist).
+Both modes sweep n in {1e3, 1e4, 1e5}; full mode lengthens the horizon.
+n = 1e6 is out of reach until the kernel blocks its server axis: its
+resident state exceeds the scoped VMEM, and ``care_route_pallas`` refuses
+it (``VmemLimitError``).
 """
 from __future__ import annotations
 
@@ -41,7 +42,6 @@ from benchmarks import common
 from repro.core.care import slotted_sim
 
 SWEEP = (1_000, 10_000, 100_000)
-FULL_EXTRA = (1_000_000,)
 QUICK_SLOTS = 1_000
 FULL_SLOTS = 4_000
 X = 3
@@ -84,29 +84,27 @@ def _timed(cfg: slotted_sim.SimConfig):
 
 def run(quick: bool = False) -> list[dict]:
     slots = QUICK_SLOTS if quick else FULL_SLOTS
-    sweep = SWEEP if quick else SWEEP + FULL_EXTRA
     rows: list[dict] = []
 
     parity = True
     walls: dict[int, dict[str, float]] = {}
     kernel_res: dict[int, slotted_sim.SimResult] = {}
-    for k in sweep:
+    for k in SWEEP:
         rp, cold_p, warm_p = _timed(_cfg(k, slots, "pallas"))
         kernel_res[k] = rp
         walls[k] = {"pallas": warm_p, "pallas_cold": cold_p}
-        if k in SWEEP:  # dense reference runs only at feasible scales
-            rd, cold_d, warm_d = _timed(_cfg(k, slots, "dense"))
-            walls[k]["dense"] = warm_d
-            parity = parity and (
-                rd.messages == rp.messages
-                and rd.departures == rp.departures
-                and rd.max_aq == rp.max_aq
-                and rd.queue_gap_sup == rp.queue_gap_sup
-                and np.array_equal(
-                    rd.per_server_arrivals, rp.per_server_arrivals
-                )
-                and np.array_equal(rd.final_q, rp.final_q)
+        rd, cold_d, warm_d = _timed(_cfg(k, slots, "dense"))
+        walls[k]["dense"] = warm_d
+        parity = parity and (
+            rd.messages == rp.messages
+            and rd.departures == rp.departures
+            and rd.max_aq == rp.max_aq
+            and rd.queue_gap_sup == rp.queue_gap_sup
+            and np.array_equal(
+                rd.per_server_arrivals, rp.per_server_arrivals
             )
+            and np.array_equal(rd.final_q, rp.final_q)
+        )
 
         label = _label(k)
         aq_bound = rp.max_aq <= X - 1
@@ -154,13 +152,13 @@ def run(quick: bool = False) -> list[dict]:
     dense_big = SWEEP[-1]
     extra = {f"dense_{_label(k)}_s": walls[k]["dense"] for k in SWEEP}
     extra.update(
-        {f"pallas_{_label(k)}_s": walls[k]["pallas"] for k in sweep}
+        {f"pallas_{_label(k)}_s": walls[k]["pallas"] for k in SWEEP}
     )
     rows.append(
         common.row(
             "route/crossover",
             sum(w["pallas"] for w in walls.values()),
-            slots * len(sweep),
+            slots * len(SWEEP),
             common.fmt_derived(
                 crossover="none" if cross is None else _label(cross),
                 speedup_at_1e5=walls[dense_big]["dense"]
@@ -176,9 +174,9 @@ def run(quick: bool = False) -> list[dict]:
     # SSC at mean-field scale: the sup queue gap is O(1) in n, so the
     # sqrt(n)-scaled gap collapses monotonically through the sweep.
     scaled = {
-        k: kernel_res[k].queue_gap_sup / np.sqrt(k) for k in sweep
+        k: kernel_res[k].queue_gap_sup / np.sqrt(k) for k in SWEEP
     }
-    for k in sweep:
+    for k in SWEEP:
         rows.append(
             common.row(
                 f"route/ssc/n{_label(k)}",
@@ -192,7 +190,7 @@ def run(quick: bool = False) -> list[dict]:
             )
         )
     collapses = all(
-        scaled[b] <= scaled[a] for a, b in zip(sweep, sweep[1:])
+        scaled[b] <= scaled[a] for a, b in zip(SWEEP, SWEEP[1:])
     )
     rows.append(
         common.row(
@@ -200,8 +198,8 @@ def run(quick: bool = False) -> list[dict]:
             0.0,
             slots,
             common.fmt_derived(
-                scaled_gap_first=float(scaled[sweep[0]]),
-                scaled_gap_last=float(scaled[sweep[-1]]),
+                scaled_gap_first=float(scaled[SWEEP[0]]),
+                scaled_gap_last=float(scaled[SWEEP[-1]]),
                 collapses=collapses,
             ),
             collapses=bool(collapses),

@@ -24,6 +24,7 @@ import time
 from typing import Sequence
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.core.care import slotted_sim
@@ -87,14 +88,17 @@ def percell_reference(
 ):
     """The pre-grid behaviour: one fresh compiled program per cell.
 
-    Mirrors the old ``simulate_batch`` exactly -- a vmapped scan per
-    ``SimConfig``, sharded over local devices only when the seed count
-    divides them (the old ``pmap`` condition) -- but built fresh per cell
-    so every cell pays its own compile, as it did when every scenario knob
-    was a static jit argument.  Cells sharing a ``static_part()`` replay
-    the same workload stream as the fused grid, so results are comparable
-    bit for bit; benchmarks use this as the golden reference the fused
-    path must reproduce (``grid_matches_percell`` rows).
+    Mirrors the old ``simulate_batch`` -- a vmapped scan per ``SimConfig``,
+    sharded over local devices only when the seed count divides them (the
+    old ``pmap`` condition) -- built fresh per cell so every cell pays its
+    own compile.  The cell's scenario enters as a traced argument, as in
+    the grid: baked in as a compile-time constant it lets XLA rewrite the
+    arithmetic on it (a division by the constant geometric ``log1p`` turns
+    into a multiplication), which moves the odd service size across a
+    floor boundary.  Cells sharing a ``static_part()`` replay the same
+    workload stream as the fused grid, so results are comparable bit for
+    bit; benchmarks use this as the golden reference the fused path must
+    reproduce (``grid_matches_percell`` rows).
     """
     keys = slotted_sim._as_keys(list(seeds))
     n_dev = jax.local_device_count()
@@ -102,17 +106,15 @@ def percell_reference(
         n_dev = 1
     results = []
     for cfg in cfgs:
-        static, scn = cfg.static_part(), cfg.scenario()
-        batched = jax.vmap(lambda key: slotted_sim._run_one(key, scn, static))
-        if n_dev > 1:
-            from jax.experimental.shard_map import shard_map
-            from jax.sharding import Mesh, PartitionSpec as P
-
-            mesh = Mesh(np.asarray(jax.local_devices()[:n_dev]), ("runs",))
-            batched = shard_map(
-                batched, mesh=mesh, in_specs=(P("runs"),), out_specs=P("runs")
-            )
-        out = jax.jit(batched)(keys)
+        static = cfg.static_part()
+        scn = jax.tree.map(
+            lambda a: jnp.broadcast_to(a, (len(seeds),) + jnp.shape(a)),
+            cfg.scenario(),
+        )
+        batched = jax.vmap(
+            lambda key, s: slotted_sim._run_one(key, s, static)
+        )
+        out = jax.jit(slotted_sim.shard_runs(batched, n_dev, 2))(keys, scn)
         out_np = [np.asarray(o) for o in out]
         results.append(
             [

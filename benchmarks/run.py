@@ -62,7 +62,7 @@ import sys
 import time
 
 # Expose the host's cores as separate XLA CPU devices so simulate_batch can
-# shard seed sweeps across them (pmap); the slotted scan fuses into a
+# shard seed sweeps across them (shard_map); the slotted scan fuses into a
 # compute-bound single-core loop, so device-level parallelism is the only
 # CPU lever.  Set before any jax import; respects an operator-provided
 # XLA_FLAGS.
@@ -72,6 +72,8 @@ if "jax" not in sys.modules and "XLA_FLAGS" not in os.environ:
         os.environ["XLA_FLAGS"] = (
             f"--xla_force_host_platform_device_count={_n_dev}"
         )
+
+from repro.compile_cache import use_compile_cache  # noqa: E402
 
 BENCHES = [
     "bench_comm_vs_error",
@@ -114,6 +116,7 @@ def main(argv=None) -> int:
         help="also write all rows as a JSON list of records to this path",
     )
     args = ap.parse_args(argv)
+    use_compile_cache()
     if args.json:
         # Fail fast on an unwritable path rather than at the end of a run.
         open(args.json, "w").close()

@@ -1,0 +1,30 @@
+"""Where JAX keeps its persistent compilation cache for this checkout.
+
+A chip run compiles every program of a grid cold; the persistent cache
+lets later processes of the same checkout load them instead.  The cache
+directory is part of each entry's key, so it must not move between runs:
+either the operator's ``JAX_COMPILATION_CACHE_DIR`` or a fixed directory
+inside the checkout -- never a temporary name, a pid or a time.
+"""
+from __future__ import annotations
+
+import os
+import pathlib
+
+import jax
+
+CHECKOUT = pathlib.Path(__file__).resolve().parents[2]
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; return its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    nothing is set here.  Otherwise the cache is ``<checkout>/.jax_cache``.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = str(CHECKOUT / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path

@@ -102,7 +102,6 @@ from typing import Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.care import approx as approx_lib
@@ -1114,16 +1113,28 @@ def _grid_fn(static: StaticConfig, n_dev: int):
     (plain jitted vmap), which is also the path `shard=False` forces.
     """
     batched = jax.vmap(lambda key, scn: _run_one(key, scn, static))
-    if n_dev <= 1:
-        fn = jax.jit(batched)
-    else:
-        mesh = Mesh(np.asarray(jax.local_devices()[:n_dev]), ("runs",))
-        fn = jax.jit(shard_map(
-            batched, mesh=mesh, in_specs=(P("runs"), P("runs")),
-            out_specs=P("runs"),
-        ))
+    fn = jax.jit(shard_runs(batched, n_dev, 2))
     _GRID_PROGRAMS.append(fn)
     return fn
+
+
+def shard_runs(batched, n_dev: int, n_args: int):
+    """Shard a vmapped per-run function's leading run axis over devices.
+
+    ``n_dev <= 1`` returns ``batched`` unchanged.  Otherwise each of the
+    first ``n_dev`` local devices runs ``1/n_dev`` of the runs (the
+    caller pads the run axis to a multiple).  The scan carries start
+    from constants that the per-run inputs then make device-varying, so
+    the varying-axes check is off (``check_vma=False``); it is a type
+    check only, and runs never communicate.
+    """
+    if n_dev <= 1:
+        return batched
+    mesh = Mesh(np.asarray(jax.local_devices()[:n_dev]), ("runs",))
+    return jax.shard_map(
+        batched, mesh=mesh, in_specs=(P("runs"),) * n_args,
+        out_specs=P("runs"), check_vma=False,
+    )
 
 
 def grid_compile_count() -> int:
@@ -1135,12 +1146,7 @@ def grid_compile_count() -> int:
     that counts too -- this is real compile work, not wrapper
     instantiations.
     """
-    # _cache_size is a private jax API (present on the pinned 0.4.x); a
-    # future jax that drops it degrades to counting wrapper instantiations
-    # rather than breaking every quick-mode benchmark run.
-    return sum(
-        getattr(f, "_cache_size", lambda: 1)() for f in _GRID_PROGRAMS
-    )
+    return sum(f._cache_size() for f in _GRID_PROGRAMS)
 
 
 def _check_pallas_static(static: StaticConfig) -> None:
@@ -1492,6 +1498,54 @@ def simulate(key: jax.Array, cfg: SimConfig) -> SimResult:
     return _finalize(np.asarray(out[0]), out[1:])
 
 
+def grid_program(
+    keys: jax.Array | Sequence[int],
+    static_cfg: StaticConfig,
+    scenarios: Scenario | Sequence[Scenario],
+    *,
+    shard: bool = True,
+):
+    """The compiled program and operands of one :func:`simulate_grid` call.
+
+    Returns ``(fn, args, (c, s))``: ``fn(*args)`` is the grid run, whose
+    first ``c * s`` outputs along the run axis are the flattened
+    cell-major ``(cell, seed)`` runs (the rest is device padding).
+    ``fn.lower(*args).compile()`` gives the program's compile time,
+    memory and HLO without running it.
+    """
+    keys = _as_keys(keys)
+    if isinstance(scenarios, Scenario):
+        scn_stacked = scenarios
+        c = int(jax.tree.leaves(scenarios)[0].shape[0])
+    else:
+        scenarios = list(scenarios)
+        c = len(scenarios)
+        scn_stacked = stack_scenarios(scenarios)
+    _check_diurnal_peak(static_cfg, scn_stacked)
+    _check_control_plane(static_cfg, scn_stacked)
+    s = keys.shape[0]
+    n = c * s
+
+    # Flatten cell-major: run r = cell * S + seed.
+    keys_flat = jnp.broadcast_to(keys[None], (c, s)).reshape((n,))
+    scn_flat = jax.tree.map(
+        lambda a: jnp.repeat(a, s, axis=0), scn_stacked
+    )
+
+    if static_cfg.route_backend == "pallas":
+        # The kernel's grid axis is the flattened run axis itself; no
+        # shard_map (the mean-field path targets one big accelerator).
+        _check_pallas_static(static_cfg)
+        return _pallas_grid_fn(static_cfg), (keys_flat, scn_flat), (c, s)
+
+    n_dev = jax.local_device_count() if shard else 1
+    idx = _pad_indices(n, n_dev)
+    if len(idx) != n:
+        keys_flat = keys_flat[idx]
+        scn_flat = jax.tree.map(lambda a: a[idx], scn_flat)
+    return _grid_fn(static_cfg, n_dev), (keys_flat, scn_flat), (c, s)
+
+
 def simulate_grid(
     keys: jax.Array | Sequence[int],
     static_cfg: StaticConfig,
@@ -1520,50 +1574,8 @@ def simulate_grid(
       ``tests/test_grid.py``): vmap, shard_map and padding are all
       semantics-preserving.
     """
-    keys = _as_keys(keys)
-    if isinstance(scenarios, Scenario):
-        scn_stacked = scenarios
-        c = int(jax.tree.leaves(scenarios)[0].shape[0])
-    else:
-        scenarios = list(scenarios)
-        c = len(scenarios)
-        scn_stacked = stack_scenarios(scenarios)
-    _check_diurnal_peak(static_cfg, scn_stacked)
-    _check_control_plane(static_cfg, scn_stacked)
-    s = keys.shape[0]
-    n = c * s
-
-    # Flatten cell-major: run r = cell * S + seed.
-    keys_flat = jnp.broadcast_to(keys[None], (c, s)).reshape((n,))
-    scn_flat = jax.tree.map(
-        lambda a: jnp.repeat(a, s, axis=0), scn_stacked
-    )
-
-    if static_cfg.route_backend == "pallas":
-        # The kernel's grid axis is the flattened run axis itself; no
-        # shard_map (the mean-field path targets one big accelerator).
-        _check_pallas_static(static_cfg)
-        out = _pallas_grid_fn(static_cfg)(keys_flat, scn_flat)
-        out_np = [np.asarray(o) for o in out]
-        arrive, rest = out_np[0], out_np[1:]
-        return [
-            [
-                _finalize(
-                    arrive[i * s + j], tuple(o[i * s + j] for o in rest)
-                )
-                for j in range(s)
-            ]
-            for i in range(c)
-        ]
-
-    n_dev = jax.local_device_count() if shard else 1
-    idx = _pad_indices(n, n_dev)
-    if len(idx) != n:
-        keys_flat = keys_flat[idx]
-        scn_flat = jax.tree.map(lambda a: a[idx], scn_flat)
-
-    out = _grid_fn(static_cfg, n_dev)(keys_flat, scn_flat)
-    out_np = [np.asarray(o)[:n] for o in out]
+    fn, args, (c, s) = grid_program(keys, static_cfg, scenarios, shard=shard)
+    out_np = [np.asarray(o)[: c * s] for o in fn(*args)]
     arrive, rest = out_np[0], out_np[1:]
     return [
         [

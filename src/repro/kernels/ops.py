@@ -33,37 +33,14 @@ def jsaq_route(
 ) -> tuple[jax.Array, jax.Array]:
     """Batched JSAQ dispatch (see kernels/jsaq_route.py).
 
-    Pads the domain axis to the tile size and the server axis to a full
-    128-lane tile; (D, K) -> ((D,N) idx, (D,K) q').  Pad *lanes* are
-    masked to the dtype's max so the argmin can never route to one (on a
-    real TPU an unmasked lane-tile pad holds undefined values); pad rows
-    are sliced off on the way out.
+    (D, K) -> ((D, N) idx, (D, K) q').  The kernel runs one domain per
+    program and pads each domain's server slab with ``int32`` max, so the
+    argmin can never route to a pad.
     """
     if not use_pallas:
         return _ref.jsaq_route_ref(q_app, num_jobs)
     interpret = _default_interpret() if interpret is None else interpret
-    d, k = q_app.shape
-    tile = _jsaq.DOMAIN_TILE
-    pad = (-d) % tile
-    if pad:
-        q_app = jnp.concatenate(
-            [q_app, jnp.zeros((pad, k), q_app.dtype)], axis=0
-        )
-    kp = _jsaq.lane_pad(k)
-    if kp != k:
-        q_app = jnp.concatenate(
-            [
-                q_app,
-                jnp.full(
-                    (q_app.shape[0], kp - k),
-                    jnp.iinfo(q_app.dtype).max,
-                    q_app.dtype,
-                ),
-            ],
-            axis=1,
-        )
-    idx, q_out = _jsaq.jsaq_route_pallas(q_app, num_jobs, interpret=interpret)
-    return idx[:d], q_out[:d, :k]
+    return _jsaq.jsaq_route_pallas(q_app, num_jobs, interpret=interpret)
 
 
 @functools.partial(

@@ -84,6 +84,7 @@ from repro.core.care import comm as comm_lib
 from repro.core.care import metrics as metrics_lib
 from repro.core.care import routing as routing_lib
 from repro.core.care import workload as workload_lib
+from repro.core.care.slotted_sim import _pad_indices, shard_runs
 from repro.kernels import ops as kernel_ops
 
 # The serving tier's routing-policy suite (paper Sec 2.1.4 restated for
@@ -1926,17 +1927,7 @@ def _serve_grid_fn(static: EngineStatic, n_cap: int, n_dev: int):
             ack_u, n_cap, scn, static
         )
     )
-    if n_dev <= 1:
-        fn = jax.jit(batched)
-    else:
-        from jax.experimental.shard_map import shard_map
-        from jax.sharding import Mesh, PartitionSpec as P
-
-        mesh = Mesh(np.asarray(jax.local_devices()[:n_dev]), ("runs",))
-        spec = (P("runs"),) * 10
-        fn = jax.jit(
-            shard_map(batched, mesh=mesh, in_specs=spec, out_specs=P("runs"))
-        )
+    fn = jax.jit(shard_runs(batched, n_dev, 10))
     _SERVE_GRID_PROGRAMS.append(fn)
     return fn
 
@@ -1948,9 +1939,7 @@ def serve_compile_count() -> int:
     compiled-shape cache sizes of every jitted grid wrapper, so batch-shape
     retraces count as the real compile work they are.
     """
-    return sum(
-        getattr(f, "_cache_size", lambda: 1)() for f in _SERVE_GRID_PROGRAMS
-    )
+    return sum(f._cache_size() for f in _SERVE_GRID_PROGRAMS)
 
 
 @dataclasses.dataclass
@@ -2080,38 +2069,21 @@ def _pad_workload(wl: ServeWorkload, t_pad: int, a_pad: int, d: int = 0,
             pad_cp(wl.net_jit_u), pad_cp(wl.fault_u), pad_ack(wl.ack_u))
 
 
-def serve_grid(
+def serve_grid_program(
     seeds: Sequence[int],
     static: EngineStatic,
     cells: Sequence[ServeConfig],
     *,
     shard: bool = True,
-) -> list[list[ServeResult]]:
-    """Run a whole serving grid as **one compiled program**.
+):
+    """The compiled program and operands of one :func:`serve_grid` call.
 
-    Args:
-      seeds: integer seeds; every cell replays the same seed set (the
-        workload sampler is host-side numpy, keyed per (cell workload
-        parameters, seed) -- cells differing only in comm thresholds share
-        streams, the paper's comparison method).
-      static: the shared program structure.  Every cell's
-        ``static_part()`` must agree with it on shapes and comm kind;
-        ``static.slots`` is the padded scan length (>= every cell's
-        ``slots``) and ``static.max_arrivals`` the arrival-lane width
-        (``0`` = derive from the sampled batch, rounded up to a multiple
-        of 8 so near-miss batches reuse the program).
-      cells: the grid cells (scenario operands + workload parameters).
-      shard: shard the flattened ``(C*S,)`` run axis across local devices
-        with ``shard_map`` (ragged batches padded with wrap-around
-        duplicates, dropped on output).
-
-    Returns:
-      ``results[c][s]`` -- one :class:`ServeResult` per (cell, seed),
-      bit-identical to the numpy reference ``run_serving_sim`` (asserted
-      by ``tests/test_serve_engine.py``).
+    Samples and pads every (cell, seed) workload.  Returns ``(fn, args,
+    wls, static)``: ``fn(*args)`` is the grid run over the flattened
+    cell-major runs (``wls[c][s]`` their workloads, ``static`` the program
+    structure with its lane width resolved); ``fn.lower(*args).compile()``
+    gives the program's compile time, memory and HLO without running it.
     """
-    from repro.core.care.slotted_sim import _pad_indices
-
     cells = list(cells)
     seeds = [int(s) for s in seeds]
     for cell in cells:
@@ -2162,8 +2134,45 @@ def serve_grid(
         arrs = [a[idx] for a in arrs]
         scn_flat = jax.tree.map(lambda a: a[idx], scn_flat)
 
-    out = _serve_grid_fn(static, n_cap, n_dev)(*arrs, scn_flat)
-    out_np = [np.asarray(o)[:n] for o in out]
+    fn = _serve_grid_fn(static, n_cap, n_dev)
+    return fn, (*arrs, scn_flat), wls, static
+
+
+def serve_grid(
+    seeds: Sequence[int],
+    static: EngineStatic,
+    cells: Sequence[ServeConfig],
+    *,
+    shard: bool = True,
+) -> list[list[ServeResult]]:
+    """Run a whole serving grid as **one compiled program**.
+
+    Args:
+      seeds: integer seeds; every cell replays the same seed set (the
+        workload sampler is host-side numpy, keyed per (cell workload
+        parameters, seed) -- cells differing only in comm thresholds share
+        streams, the paper's comparison method).
+      static: the shared program structure.  Every cell's
+        ``static_part()`` must agree with it on shapes and comm kind;
+        ``static.slots`` is the padded scan length (>= every cell's
+        ``slots``) and ``static.max_arrivals`` the arrival-lane width
+        (``0`` = derive from the sampled batch, rounded up to a multiple
+        of 8 so near-miss batches reuse the program).
+      cells: the grid cells (scenario operands + workload parameters).
+      shard: shard the flattened ``(C*S,)`` run axis across local devices
+        with ``shard_map`` (ragged batches padded with wrap-around
+        duplicates, dropped on output).
+
+    Returns:
+      ``results[c][s]`` -- one :class:`ServeResult` per (cell, seed),
+      bit-identical to the numpy reference ``run_serving_sim`` (asserted
+      by ``tests/test_serve_engine.py``).
+    """
+    fn, args, wls, static = serve_grid_program(
+        seeds, static, cells, shard=shard
+    )
+    n = sum(len(row) for row in wls)
+    out_np = [np.asarray(o)[:n] for o in fn(*args)]
     base, retrans, occ = _split_extra_outs(out_np, static)
     s = len(seeds)
     return [
@@ -2176,7 +2185,7 @@ def serve_grid(
             )
             for j in range(s)
         ]
-        for c in range(len(cells))
+        for c in range(len(wls))
     ]
 
 
@@ -2470,9 +2479,7 @@ def _stream_step_fn(static: EngineStatic):
 
 def stream_compile_count() -> int:
     """Compiled chunk-step programs so far (same accounting as the grid)."""
-    return sum(
-        getattr(f, "_cache_size", lambda: 1)() for f in _STREAM_PROGRAMS
-    )
+    return sum(f._cache_size() for f in _STREAM_PROGRAMS)
 
 
 @dataclasses.dataclass

@@ -23,19 +23,23 @@ from repro.core.care import slotted_sim, workload
 KEY7 = jax.random.key(7)
 
 # Captured from the seed simulator (commit 7874f0a) at slots=20_000,
-# key=jax.random.key(7): (messages, max_aq, departures, arrivals, mean_jct).
+# key=jax.random.key(7): (messages, max_aq, departures, arrivals, mean_jct);
+# recaptured unchanged in code when JAX made ``jax_threefry_partitionable``
+# its default, which re-draws every ``jax.random`` stream (the seed-era
+# values still reproduce under ``JAX_THREEFRY_PARTITIONABLE=0``).
 SLOTTED_GOLDEN = {
-    ("et", "msr", 3, 0.95, "jsaq"): (2087, 2, 18913, 18994, 79.80040184000423),
-    ("et", "msr", 5, 0.9, "jsaq"): (421, 4, 17859, 17964, 90.51251469847136),
-    ("et", "msr_x", 3, 0.95, "jsaq"): (3888, 2, 18922, 18994, 65.55623084240567),
-    ("dt", "msr_x", 3, 0.9, "jsaq"): (5956, 2, 17899, 17964, 55.152857701547575),
-    ("dt", "basic", 2, 0.8, "jsaq"): (7998, 1, 16015, 16040, 37.49572275991258),
-    ("rt", "msr", 3, 0.9, "jsaq"): (6000, 4, 17889, 17964, 70.11940298507463),
-    ("none", "msr", 3, 0.95, "jsq"): (0, 40, 18950, 18994, 37.47646437994723),
+    ("et", "msr", 3, 0.95, "jsaq"): (2163, 2, 18912, 19006, 85.57392131979695),
+    ("et", "msr", 5, 0.9, "jsaq"): (441, 4, 17908, 18010, 96.3022113022113),
+    ("et", "msr_x", 3, 0.95, "jsaq"): (3889, 2, 18927, 19006, 69.79563586410947),
+    ("dt", "msr_x", 3, 0.9, "jsaq"): (5969, 2, 17942, 18010, 56.68492921636384),
+    ("dt", "basic", 2, 0.8, "jsaq"): (7994, 1, 16004, 16038, 37.846725818545366),
+    ("rt", "msr", 3, 0.9, "jsaq"): (6000, 5, 17929, 18010, 71.81538289921356),
+    ("none", "msr", 3, 0.95, "jsq"): (0, 64, 18951, 19006, 41.97440768297187),
 }
 
-# Seed dispatch simulator at steps=120, x=2, seed=0: messages per comm mode.
-DISPATCH_GOLDEN = {"exact": 960, "dt": 480, "et": 652, "off": 0}
+# Seed dispatch simulator at steps=120, x=2, seed=0: messages per comm mode
+# (recaptured likewise).
+DISPATCH_GOLDEN = {"exact": 960, "dt": 480, "et": 686, "off": 0}
 
 
 class TestGoldenRegression:

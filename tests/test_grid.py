@@ -11,9 +11,9 @@ Three layers of protection for the "one compiled program per figure" path:
 2. **Grid equivalence** -- ``simulate_grid`` must equal per-cell
    ``simulate`` on fixed seeds (messages, max_aq, full JCT arrays):
    vmap / shard_map / padding are all semantics-preserving.
-3. **Topology** -- padding indices are exercised directly, and a
-   subprocess forced to 8 host devices re-runs a ragged grid (3 runs over
-   8 shards) that must match the in-process device count's results.
+3. **Topology** -- padding indices are exercised directly, and
+   subprocesses forced to several host devices re-run a ragged grid of
+   each tier (3 runs over 8 or 4 shards) that must match one device.
 """
 from __future__ import annotations
 
@@ -55,30 +55,35 @@ GOLDEN_CELLS = {
 }
 
 # Captured from the seed implementation (SimConfig fully static) at the
-# commit introducing the split; keys are (cell, seed) -> fingerprint.
+# commit introducing the split, then recaptured unchanged in code when JAX
+# made ``jax_threefry_partitionable`` its default (it re-draws every
+# ``jax.random`` stream).  Under ``JAX_THREEFRY_PARTITIONABLE=0`` the
+# seed-era fingerprints still reproduce exactly, so the traced path did
+# not drift -- only the library's key derivation did.  Keys are
+# (cell, seed) -> fingerprint.
 GOLDENS = json.loads("""
-{"et_msr/s0":{"messages":417,"max_aq":2,"departures":3740,"arrivals":3815,"dropped":0,"max_queue":6,"gap_sup":6,"jct_sum":301134,"jct_n":3740,"per_srv_sum":55473},
-"et_msr/s7":{"messages":379,"max_aq":2,"departures":3720,"arrivals":3791,"dropped":0,"max_queue":6,"gap_sup":6,"jct_sum":294476,"jct_n":3720,"per_srv_sum":55018},
-"et_msr_x5/s0":{"messages":53,"max_aq":4,"departures":3159,"arrivals":3207,"dropped":0,"max_queue":6,"gap_sup":6,"jct_sum":201963,"jct_n":3159,"per_srv_sum":46925},
-"et_msr_x5/s7":{"messages":42,"max_aq":4,"departures":3160,"arrivals":3211,"dropped":0,"max_queue":5,"gap_sup":5,"jct_sum":189754,"jct_n":3160,"per_srv_sum":46340},
-"dt_msrx/s0":{"messages":1178,"max_aq":2,"departures":3568,"arrivals":3619,"dropped":0,"max_queue":5,"gap_sup":5,"jct_sum":200744,"jct_n":3568,"per_srv_sum":52187},
-"dt_msrx/s7":{"messages":1177,"max_aq":2,"departures":3559,"arrivals":3599,"dropped":0,"max_queue":5,"gap_sup":5,"jct_sum":187847,"jct_n":3559,"per_srv_sum":52847},
-"rt/s0":{"messages":2400,"max_aq":4,"departures":3563,"arrivals":3619,"dropped":0,"max_queue":4,"gap_sup":4,"jct_sum":216091,"jct_n":3563,"per_srv_sum":52046},
-"rt/s7":{"messages":2400,"max_aq":4,"departures":3549,"arrivals":3599,"dropped":0,"max_queue":4,"gap_sup":4,"jct_sum":207202,"jct_n":3549,"per_srv_sum":51905},
-"et_rt/s0":{"messages":1200,"max_aq":2,"departures":1940,"arrivals":1958,"dropped":0,"max_queue":3,"gap_sup":3,"jct_sum":71989,"jct_n":1940,"per_srv_sum":28616},
-"et_rt/s7":{"messages":1200,"max_aq":2,"departures":1973,"arrivals":1989,"dropped":0,"max_queue":3,"gap_sup":3,"jct_sum":69378,"jct_n":1973,"per_srv_sum":28863},
-"jsq/s0":{"messages":0,"max_aq":20,"departures":3773,"arrivals":3815,"dropped":0,"max_queue":3,"gap_sup":3,"jct_sum":150163,"jct_n":3773,"per_srv_sum":54425},
-"jsq/s7":{"messages":0,"max_aq":21,"departures":3763,"arrivals":3791,"dropped":0,"max_queue":2,"gap_sup":2,"jct_sum":141963,"jct_n":3763,"per_srv_sum":55419},
-"sq2/s0":{"messages":0,"max_aq":22,"departures":3728,"arrivals":3815,"dropped":0,"max_queue":8,"gap_sup":8,"jct_sum":350621,"jct_n":3728,"per_srv_sum":55535},
-"sq2/s7":{"messages":0,"max_aq":15,"departures":3692,"arrivals":3791,"dropped":0,"max_queue":8,"gap_sup":8,"jct_sum":370990,"jct_n":3692,"per_srv_sum":54888},
-"rr/s0":{"messages":0,"max_aq":19,"departures":3634,"arrivals":3815,"dropped":0,"max_queue":20,"gap_sup":20,"jct_sum":550694,"jct_n":3634,"per_srv_sum":55299},
-"rr/s7":{"messages":0,"max_aq":19,"departures":3613,"arrivals":3791,"dropped":0,"max_queue":20,"gap_sup":20,"jct_sum":532031,"jct_n":3613,"per_srv_sum":54889},
-"mmpp/s0":{"messages":413,"max_aq":2,"departures":3706,"arrivals":3778,"dropped":0,"max_queue":5,"gap_sup":5,"jct_sum":295680,"jct_n":3706,"per_srv_sum":55010},
-"mmpp/s7":{"messages":379,"max_aq":2,"departures":3714,"arrivals":3791,"dropped":0,"max_queue":6,"gap_sup":5,"jct_sum":282532,"jct_n":3714,"per_srv_sum":54871},
-"hetero/s0":{"messages":465,"max_aq":2,"departures":3728,"arrivals":3815,"dropped":0,"max_queue":7,"gap_sup":7,"jct_sum":317649,"jct_n":3728,"per_srv_sum":39668},
-"hetero/s7":{"messages":415,"max_aq":2,"departures":3708,"arrivals":3791,"dropped":0,"max_queue":8,"gap_sup":8,"jct_sum":314079,"jct_n":3708,"per_srv_sum":39191},
-"basic/s0":{"messages":874,"max_aq":3,"departures":3554,"arrivals":3619,"dropped":0,"max_queue":5,"gap_sup":5,"jct_sum":220946,"jct_n":3554,"per_srv_sum":51588},
-"basic/s7":{"messages":878,"max_aq":3,"departures":3550,"arrivals":3599,"dropped":0,"max_queue":5,"gap_sup":5,"jct_sum":213382,"jct_n":3550,"per_srv_sum":52433}}
+{"et_msr/s0":{"messages":444,"max_aq":2,"departures":3715,"arrivals":3785,"dropped":0,"max_queue":7,"gap_sup":7,"jct_sum":341466,"jct_n":3715,"per_srv_sum":55740},
+"et_msr/s7":{"messages":387,"max_aq":2,"departures":3719,"arrivals":3802,"dropped":0,"max_queue":6,"gap_sup":6,"jct_sum":283899,"jct_n":3719,"per_srv_sum":54948},
+"et_msr_x5/s0":{"messages":67,"max_aq":4,"departures":3138,"arrivals":3182,"dropped":0,"max_queue":6,"gap_sup":6,"jct_sum":211483,"jct_n":3138,"per_srv_sum":46289},
+"et_msr_x5/s7":{"messages":59,"max_aq":4,"departures":3176,"arrivals":3235,"dropped":0,"max_queue":5,"gap_sup":5,"jct_sum":194362,"jct_n":3176,"per_srv_sum":46738},
+"dt_msrx/s0":{"messages":1171,"max_aq":2,"departures":3539,"arrivals":3582,"dropped":0,"max_queue":5,"gap_sup":5,"jct_sum":213771,"jct_n":3539,"per_srv_sum":51892},
+"dt_msrx/s7":{"messages":1174,"max_aq":2,"departures":3551,"arrivals":3611,"dropped":0,"max_queue":4,"gap_sup":4,"jct_sum":189439,"jct_n":3551,"per_srv_sum":52067},
+"rt/s0":{"messages":2400,"max_aq":4,"departures":3537,"arrivals":3582,"dropped":0,"max_queue":5,"gap_sup":5,"jct_sum":231527,"jct_n":3537,"per_srv_sum":52340},
+"rt/s7":{"messages":2400,"max_aq":4,"departures":3552,"arrivals":3611,"dropped":0,"max_queue":4,"gap_sup":4,"jct_sum":202628,"jct_n":3552,"per_srv_sum":52406},
+"et_rt/s0":{"messages":1200,"max_aq":2,"departures":1981,"arrivals":1998,"dropped":0,"max_queue":3,"gap_sup":3,"jct_sum":72852,"jct_n":1981,"per_srv_sum":28885},
+"et_rt/s7":{"messages":1200,"max_aq":2,"departures":1986,"arrivals":2005,"dropped":0,"max_queue":3,"gap_sup":3,"jct_sum":72984,"jct_n":1986,"per_srv_sum":29082},
+"jsq/s0":{"messages":0,"max_aq":25,"departures":3758,"arrivals":3785,"dropped":0,"max_queue":3,"gap_sup":3,"jct_sum":176458,"jct_n":3758,"per_srv_sum":55296},
+"jsq/s7":{"messages":0,"max_aq":28,"departures":3761,"arrivals":3802,"dropped":0,"max_queue":3,"gap_sup":3,"jct_sum":137055,"jct_n":3761,"per_srv_sum":55095},
+"sq2/s0":{"messages":0,"max_aq":16,"departures":3696,"arrivals":3785,"dropped":0,"max_queue":8,"gap_sup":8,"jct_sum":405079,"jct_n":3696,"per_srv_sum":54668},
+"sq2/s7":{"messages":0,"max_aq":15,"departures":3699,"arrivals":3802,"dropped":0,"max_queue":7,"gap_sup":7,"jct_sum":315801,"jct_n":3699,"per_srv_sum":55138},
+"rr/s0":{"messages":0,"max_aq":27,"departures":3596,"arrivals":3785,"dropped":0,"max_queue":28,"gap_sup":28,"jct_sum":618487,"jct_n":3596,"per_srv_sum":54733},
+"rr/s7":{"messages":0,"max_aq":23,"departures":3603,"arrivals":3802,"dropped":0,"max_queue":24,"gap_sup":24,"jct_sum":489903,"jct_n":3603,"per_srv_sum":54964},
+"mmpp/s0":{"messages":442,"max_aq":2,"departures":3736,"arrivals":3812,"dropped":0,"max_queue":6,"gap_sup":6,"jct_sum":327116,"jct_n":3736,"per_srv_sum":56267},
+"mmpp/s7":{"messages":360,"max_aq":2,"departures":3699,"arrivals":3784,"dropped":0,"max_queue":6,"gap_sup":6,"jct_sum":273547,"jct_n":3699,"per_srv_sum":54631},
+"hetero/s0":{"messages":438,"max_aq":2,"departures":3689,"arrivals":3785,"dropped":0,"max_queue":9,"gap_sup":9,"jct_sum":392042,"jct_n":3689,"per_srv_sum":40096},
+"hetero/s7":{"messages":413,"max_aq":2,"departures":3700,"arrivals":3802,"dropped":0,"max_queue":7,"gap_sup":7,"jct_sum":267313,"jct_n":3700,"per_srv_sum":39501},
+"basic/s0":{"messages":871,"max_aq":3,"departures":3535,"arrivals":3582,"dropped":0,"max_queue":5,"gap_sup":5,"jct_sum":227351,"jct_n":3535,"per_srv_sum":52029},
+"basic/s7":{"messages":875,"max_aq":3,"departures":3551,"arrivals":3611,"dropped":0,"max_queue":5,"gap_sup":5,"jct_sum":211762,"jct_n":3551,"per_srv_sum":52361}}
 """)
 
 
@@ -324,44 +329,66 @@ class TestPadding:
         assert list(idx) == [0, 1, 2, 0, 1, 2, 0, 1]
 
 
-_SUBPROCESS_SCRIPT = """
+_SUBPROCESS_PRELUDE = """
 import json, sys
 import numpy as np
 import jax
-from repro.core import SimConfig, simulate_grid
 
 assert jax.local_device_count() == {n_dev}, jax.local_device_count()
+"""
+
+# 3 cells x 1 seed = 3 runs: ragged over n_dev devices, exercising padding.
+_SUBPROCESS_SCRIPTS = {
+    "slotted": """
+from repro.core import SimConfig, simulate_grid
+
 cfgs = [
     SimConfig(slots=2000, load=0.95, x=3),
     SimConfig(slots=2000, load=0.8, x=2),
     SimConfig(slots=2000, load=0.5, x=4),
 ]
-# 3 cells x 1 seed = 3 runs: ragged over {n_dev} devices, exercising padding.
 grid = simulate_grid([11], cfgs[0].static_part(), [c.scenario() for c in cfgs])
 print(json.dumps([
     dict(messages=r[0].messages, max_aq=r[0].max_aq,
          jct=np.asarray(r[0].jct).tolist())
     for r in grid
 ]))
-"""
+""",
+    "serving": """
+from repro.serve import engine
+
+cells = [
+    engine.ServeConfig(replicas=16, decode_slots=4, slots=600, load=0.9,
+                       x=x, comm="et")
+    for x in (2, 3, 4)
+]
+grid = engine.serve_grid([11], cells[0].static_part(), cells)
+print(json.dumps([
+    dict(messages=r[0].messages, dropped=r[0].dropped,
+         jct=np.asarray(r[0].jct_by_rid).tolist())
+    for r in grid
+]))
+""",
+}
 
 
 class TestDeviceTopology:
     @pytest.mark.slow
-    def test_1_vs_8_device_consistency(self):
-        """A ragged grid forced onto 8 host devices matches 1 device."""
+    @pytest.mark.parametrize("tier,n_dev", [("slotted", 8), ("serving", 4)])
+    def test_1_vs_8_device_consistency(self, tier, n_dev):
+        """A ragged grid sharded over forced host devices matches 1 device
+        (the slotted tier over 8, the serving tier over 4)."""
         outs = {}
-        for n_dev in (1, 8):
+        for n in (1, n_dev):
             env = dict(os.environ)
-            env["XLA_FLAGS"] = (
-                f"--xla_force_host_platform_device_count={n_dev}"
-            )
+            env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n}"
             env["JAX_PLATFORMS"] = "cpu"
             env["PYTHONPATH"] = "src" + (
                 os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else ""
             )
+            script = _SUBPROCESS_PRELUDE.format(n_dev=n)
             proc = subprocess.run(
-                [sys.executable, "-c", _SUBPROCESS_SCRIPT.format(n_dev=n_dev)],
+                [sys.executable, "-c", script + _SUBPROCESS_SCRIPTS[tier]],
                 capture_output=True,
                 text=True,
                 timeout=600,
@@ -369,8 +396,8 @@ class TestDeviceTopology:
                 cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
             )
             assert proc.returncode == 0, proc.stderr[-2000:]
-            outs[n_dev] = json.loads(proc.stdout)
-        assert outs[1] == outs[8]
+            outs[n] = json.loads(proc.stdout)
+        assert outs[1] == outs[n_dev]
 
 
 # ---------------------------------------------------------------------------

@@ -58,8 +58,11 @@ def test_relative_communication_zero_departures():
 
 
 def test_simulation_with_zero_completions_yields_finite_summary():
-    # A horizon shorter than one mean service: jobs arrive, none finish.
-    cfg = slotted_sim.SimConfig(slots=5, load=1.0, mean_service=50)
+    # A horizon shorter than one (deterministic) service: jobs arrive,
+    # none can finish, whatever the random stream.
+    cfg = slotted_sim.SimConfig(
+        slots=5, load=1.0, mean_service=50, service="deterministic"
+    )
     res = slotted_sim.simulate(__import__("jax").random.key(0), cfg)
     s = metrics.jct_summary(res.jct)
     assert res.jct.size == 0

@@ -15,7 +15,9 @@ from repro.models import model, parallel, partitioning
 def ctx():
     # Single-device "mesh" with both axes size 1: every rule must degrade
     # to replication (divisibility guard) without erroring.
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh(
+        (1, 1), ("data", "model"), axis_types=(jax.sharding.AxisType.Auto,) * 2
+    )
     return parallel.ParallelContext(mesh=mesh, dp_axes=("data",))
 
 
@@ -84,7 +86,10 @@ class TestProductionMeshSpecs:
             pytest.skip("needs the forced multi-device dryrun env")
 
     def test_divisible_helper(self):
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = jax.make_mesh(
+            (1, 1), ("data", "model"),
+            axis_types=(jax.sharding.AxisType.Auto,) * 2,
+        )
         # 7 not divisible by anything but 1 -> None
         spec = partitioning._divisible(P("model", None), (7, 4), mesh)
         assert tuple(spec) == ("model", None)  # axis size 1 divides all
