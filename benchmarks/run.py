@@ -50,7 +50,6 @@ The mapping to paper artifacts:
                            scalable JSQ) vs CARE push on one frontier
   bench_retrans         -> beyond-paper: reliable (ack'd) control-plane
                            transport vs fire-and-forget under loss
-  bench_roofline        -> Sec Roofline deliverable  (from dry-run artifacts)
 """
 from __future__ import annotations
 
@@ -89,7 +88,6 @@ BENCHES = [
     "bench_faults",
     "bench_pull",
     "bench_retrans",
-    "bench_roofline",
 ]
 
 

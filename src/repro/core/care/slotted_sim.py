@@ -92,6 +92,13 @@ speed on CPU/TPU.  Batching entry points:
   with ``shard_map``.  Ragged batches are padded up to the device count
   (and the padding dropped on the way out), so they no longer fall back to
   a single device the way the old ``pmap`` path did.
+
+The compiled program names its phases with ``jax.named_scope``, so that a
+device trace's operations map back to them through the executable's
+``op_name`` metadata: ``draw`` (the workload draw), ``faults``, ``route``,
+``service``, ``drain``, ``trigger`` and ``metrics`` (the slot step, in
+order) and ``complete`` (the completion-slot scatter after the scan).
+:func:`simulate_grid` also records host spans (:mod:`repro.spans`).
 """
 from __future__ import annotations
 
@@ -104,6 +111,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
+from repro import spans
 from repro.core.care import approx as approx_lib
 from repro.core.care import comm as comm_lib
 from repro.core.care import routing as routing_lib
@@ -746,274 +754,283 @@ def _sim_core(
         fkey = rest[-1] if has_fault else None
 
         # --- 0. fault transitions -------------------------------------
-        # The server fault chain advances first: this slot's service (and
-        # trigger suppression) sees this slot's fault state, matching the
-        # numpy serving reference.  Frozen past the horizon.
-        if has_fault:
-            fault_u = jax.random.uniform(fkey, (k,), jnp.float32)
-            faulted, recovered = workload_lib.fault_transitions(
-                c.fault_state, fault_u, scn.crash_rate, scn.recover_rate
-            )
-            faulted = jnp.where(act, faulted, c.fault_state)
-            recovered = recovered & act
-        else:
-            faulted = recovered = None
+        with jax.named_scope("faults"):
+            # The server fault chain advances first: this slot's service (and
+            # trigger suppression) sees this slot's fault state, matching the
+            # numpy serving reference.  Frozen past the horizon.
+            if has_fault:
+                fault_u = jax.random.uniform(fkey, (k,), jnp.float32)
+                faulted, recovered = workload_lib.fault_transitions(
+                    c.fault_state, fault_u, scn.crash_rate, scn.recover_rate
+                )
+                faulted = jnp.where(act, faulted, c.fault_state)
+                recovered = recovered & act
+            else:
+                faulted = recovered = None
 
         # --- 1. arrival & routing -------------------------------------
-        if stale_ring:
-            hist_idx = jid - 1 - scn.net_delay
-            q_route = jnp.where(hist_idx >= 0, c.q_hist[hist_idx % cap], 0)
-        else:
-            q_route = c.q_true
-        if has_ack:
-            # Under the ack transport suspect masking is keepalive-driven:
-            # the balancer reads its last-heard clock (reset by any data
-            # *or* keepalive delivery), and a server that abandoned an
-            # update after max_retries is a self-suspect regardless of
-            # age.  An all-suspect fleet falls back to all-healthy -- the
-            # balancer must route somewhere.
-            h = (
-                (scn.suspect_age <= 0) | (c.net.ka_age <= scn.suspect_age)
-            ) & ((scn.suspect_age <= 0) | ~c.net.gave_up)
-            healthy = jnp.where(jnp.any(h), h, True)
-        elif has_net or has_fault:
-            # Staleness timeout: a server whose last delivered update is
-            # older than suspect_age is suspect and excluded from the
-            # shortest-queue candidate set (suspect_age 0 disables -- the
-            # all-True mask is decision-identical to no mask).  Without a
-            # network model delivery is instant, so the trigger counter
-            # slots_since_msg *is* the update age.
-            age = c.net.age if has_net else c.comm.slots_since_msg
-            healthy = (scn.suspect_age <= 0) | (age <= scn.suspect_age)
-        else:
-            healthy = None
-        if has_cls or static.constrained:
-            # Per-class affinity constrains the candidate set; composed
-            # with the suspect mask, an empty intersection falls back to
-            # the affinity set alone (the SLA constraint is hard, the
-            # staleness heuristic is soft) -- mirroring the SQ(d)-subset
-            # fallback of the serving tier.  With a single constrained
-            # class there is no class stream: every arrival reads row 0.
-            aff = scn.class_affinity[cls_t if has_cls else 0]
-            if healthy is not None:
-                both = aff & healthy
-                mask = jnp.where(jnp.any(both), both, aff)
+        with jax.named_scope("route"):
+            if stale_ring:
+                hist_idx = jid - 1 - scn.net_delay
+                q_route = jnp.where(hist_idx >= 0, c.q_hist[hist_idx % cap], 0)
             else:
-                mask = aff
-        else:
-            mask = healthy
-        server, rr_ptr = routing_lib.route(
-            static.policy, q_route, c.emu.q_app, c.rr_ptr, skey,
-            d=static.sqd, drain_slots=drain_slots,
-            deterministic=static.deterministic_ties,
-            mask=mask, tokens=c.tokens,
-        )
-        # Dense one-hot arithmetic instead of scalar gathers / scatters /
-        # conds: under vmap those lower to serial per-batch-element loops
-        # (or both-branch selects), which destroys the batched-scan
-        # throughput; elementwise (K,) ops stay fully vectorised.
-        onehot = jnp.arange(k, dtype=jnp.int32) == server
-        if has_pull:
-            # The balancer spends one token on every routed arrival (it
-            # cannot see FIFO drops); an empty selected pool is a token
-            # miss -- the uniform-random fallback path.
-            tok_sel = jnp.sum(jnp.where(onehot, c.tokens, 0))
-            token_miss = c.token_miss + (arr & (tok_sel == 0)).astype(
-                jnp.int32
-            )
-            tokens = jnp.maximum(
-                c.tokens - (onehot & arr).astype(jnp.int32), 0
-            )
-        else:
-            token_miss = c.token_miss
-            tokens = c.tokens
-        q_sel = jnp.sum(jnp.where(onehot, c.q_true, 0))
-        # A full FIFO drops the arrival (counted) rather than letting the
-        # tail wrap onto the live head entry.
-        admit = arr & (q_sel < b)
-        dropped = c.dropped + (arr & ~admit).astype(jnp.int32)
-        sel = onehot & admit
-        head_sel = jnp.sum(jnp.where(onehot, c.head_ptr, 0))
-        tail = (head_sel + q_sel) % b
-        # Masked one-element scatter (the ring itself still needs indexing).
-        buf_jid = c.buf_jid.at[server, tail].set(
-            jnp.where(admit, jid, c.buf_jid[server, tail])
-        )
-        q_true = c.q_true + sel.astype(jnp.int32)
-        head_rem = jnp.where(sel & (c.q_true == 0), size, c.head_rem)
-        emu = approx_lib.emu_arrival_masked(c.emu, sel, acfg)
-        arrs = c.arrs + admit.astype(jnp.int32)
-        per_srv = c.per_srv + sel.astype(jnp.int32)
-
-        # --- 2. service ------------------------------------------------
-        # Past the cell's horizon (act False) nothing serves: the mask
-        # freezes head_rem / q_true / deps exactly where the horizon left
-        # them.  `act & True` is the identity, so unpadded runs are
-        # bit-identical to the historical unmasked program.
-        busy = (q_true > 0) & act
-        if rates is None:
-            units = None
-            if has_fault:
-                eff_units = workload_lib.faulted_service_units(
-                    jid, faulted, jnp.ones((k,), jnp.int32),
-                    static.fault, scn.slow_factor,
-                )
-                head_rem = jnp.where(busy, head_rem - eff_units, head_rem)
+                q_route = c.q_true
+            if has_ack:
+                # Under the ack transport suspect masking is keepalive-driven:
+                # the balancer reads its last-heard clock (reset by any data
+                # *or* keepalive delivery), and a server that abandoned an
+                # update after max_retries is a self-suspect regardless of
+                # age.  An all-suspect fleet falls back to all-healthy -- the
+                # balancer must route somewhere.
+                h = (
+                    (scn.suspect_age <= 0) | (c.net.ka_age <= scn.suspect_age)
+                ) & ((scn.suspect_age <= 0) | ~c.net.gave_up)
+                healthy = jnp.where(jnp.any(h), h, True)
+            elif has_net or has_fault:
+                # Staleness timeout: a server whose last delivered update is
+                # older than suspect_age is suspect and excluded from the
+                # shortest-queue candidate set (suspect_age 0 disables -- the
+                # all-True mask is decision-identical to no mask).  Without a
+                # network model delivery is instant, so the trigger counter
+                # slots_since_msg *is* the update age.
+                age = c.net.age if has_net else c.comm.slots_since_msg
+                healthy = (scn.suspect_age <= 0) | (age <= scn.suspect_age)
             else:
-                head_rem = jnp.where(busy, head_rem - 1, head_rem)
-        else:
-            units = workload_lib.service_units(jid, rates)
-            if has_fault:
-                eff_units = workload_lib.faulted_service_units(
-                    jid, faulted, units, static.fault, scn.slow_factor,
-                    rates=rates,
-                )
+                healthy = None
+            if has_cls or static.constrained:
+                # Per-class affinity constrains the candidate set; composed
+                # with the suspect mask, an empty intersection falls back to
+                # the affinity set alone (the SLA constraint is hard, the
+                # staleness heuristic is soft) -- mirroring the SQ(d)-subset
+                # fallback of the serving tier.  With a single constrained
+                # class there is no class stream: every arrival reads row 0.
+                aff = scn.class_affinity[cls_t if has_cls else 0]
+                if healthy is not None:
+                    both = aff & healthy
+                    mask = jnp.where(jnp.any(both), both, aff)
+                else:
+                    mask = aff
             else:
-                eff_units = units
-            head_rem = jnp.where(busy, head_rem - eff_units, head_rem)
-        dep = busy & (head_rem <= 0)
-        departed_jid = jnp.where(
-            dep, buf_jid[jnp.arange(k), c.head_ptr % b], -1
-        )
-        q_true = jnp.where(dep, q_true - 1, q_true)
-        head_ptr = jnp.where(dep, c.head_ptr + 1, c.head_ptr)
-        # Promote the next job (if any) into service with its true size.
-        next_jid = buf_jid[jnp.arange(k), head_ptr % b]
-        next_size = sizes[jnp.clip(next_jid, 0, sizes.shape[0] - 1)]
-        head_rem = jnp.where(dep & (q_true > 0), next_size, head_rem)
-        deps = c.deps + jnp.sum(dep, dtype=jnp.int32)
-
-        # --- 3. emulation drain -----------------------------------------
-        emu = approx_lib.emu_drain_slot(emu, acfg, units=units, active=act)
-
-        # --- 4/5. communication trigger (shared core, comm.py) ----------
-        # The trigger counters (slots_since_msg in particular) must freeze
-        # past the horizon, or RT/ET+RT cells would keep messaging through
-        # the padding; evaluate unconditionally, then select the advanced
-        # state only on active slots (the identity when act is True).
-        err = approx_lib.approximation_error(emu, q_true)
-        # Crashed servers cannot send (their counters keep advancing, so
-        # the first healthy slot re-fires); a recovery force-sends a
-        # resync.  The emulation keeps draining with *nominal* units --
-        # the balancer is fault-unaware, so a crash or slowdown grows the
-        # error until the trigger or the staleness timeout reacts.
-        if has_fault and static.fault == "crash":
-            can_send, force = ~faulted, recovered
-        else:
-            can_send = force = None
-        triggered, comm_adv = comm_lib.evaluate(
-            c.comm, ccfg, err, dep.astype(jnp.int32),
-            can_send=can_send, force=force, q=q_true,
-            count_msgs=not has_net,
-        )
-        triggered = triggered & act
-        if has_ack:
-            # The ack/keepalive channels draw from a third child of the
-            # per-slot net key, so the fire_forget two-way split -- and
-            # with it every pre-existing sample path -- stays byte-stable.
-            kd, kj, ka = jax.random.split(nkey, 3)
-            delivered, payload, sent, net_adv = comm_lib.net_step_ack(
-                c.net, ncfg, triggered, q_true,
-                jax.random.uniform(kd, (k,), jnp.float32),
-                jax.random.uniform(kj, (k,), jnp.float32),
-                jax.random.uniform(ka, (4, k), jnp.float32),
-                can_send=can_send,
+                mask = healthy
+            server, rr_ptr = routing_lib.route(
+                static.policy, q_route, c.emu.q_app, c.rr_ptr, skey,
+                d=static.sqd, drain_slots=drain_slots,
+                deterministic=static.deterministic_ties,
+                mask=mask, tokens=c.tokens,
             )
-        elif has_net:
-            # can_send wipes a crashed server's queued piggyback so it
-            # cannot send its pre-crash snapshot at the next free slot --
-            # the recovery resync (force) is the re-announcement path.
-            kd, kj = jax.random.split(nkey)
-            delivered, payload, sent, net_adv = comm_lib.net_step(
-                c.net, ncfg, triggered, q_true,
-                jax.random.uniform(kd, (k,), jnp.float32),
-                jax.random.uniform(kj, (k,), jnp.float32),
-                can_send=can_send,
-            )
-        if has_net:
-            delivered = delivered & act
-            net_state = jax.tree.map(
-                lambda adv, old: jnp.where(act, adv, old), net_adv, c.net
-            )
-            # net_step owns wire accounting (piggybacking batches queued
-            # triggers into one send).
-            comm_adv = comm_lib.CommState(
-                deps_since_msg=comm_adv.deps_since_msg,
-                slots_since_msg=comm_adv.slots_since_msg,
-                msgs=comm_adv.msgs + jnp.where(act, sent, 0),
-            )
-            snap_mask, snap_payload = delivered, payload
-        else:
-            net_state = c.net
-            snap_mask, snap_payload = triggered, q_true
-        if has_net and static.policy in ("sq2", "sqd"):
-            # SQ(d)'s query implementation costs 2d messages per offered
-            # arrival (d probes + d replies), now counted as real traffic
-            # on the same axis as the push-based schemes.  The probes ride
-            # the same network: their staleness is the q_hist ring above
-            # (they are not subject to loss -- a query that must be
-            # re-issued would stall the arrival, so d is effectively the
-            # retry budget).
-            d_q = 2 if static.policy == "sq2" else static.sqd
-            comm_adv = comm_lib.CommState(
-                deps_since_msg=comm_adv.deps_since_msg,
-                slots_since_msg=comm_adv.slots_since_msg,
-                msgs=comm_adv.msgs + 2 * d_q * arr.astype(jnp.int32),
-            )
-        comm_state = jax.tree.map(
-            lambda adv, old: jnp.where(act, adv, old), comm_adv, c.comm
-        )
-        emu = approx_lib.emu_message_reset(emu, snap_payload, snap_mask, acfg)
-        if has_pull:
-            # A delivered token message overwrites that server's pool
-            # entry from the queue snapshot it carried: 1 iff idle for
-            # JIQ, the headroom below the threshold for hsq.  Stale
-            # tokens of a crashed server are spent and never refreshed,
-            # which is what bounds its misroutes.
-            if static.comm == "jiq":
-                fresh = (snap_payload == 0).astype(jnp.int32)
-            else:  # hsq
-                fresh = jnp.maximum(scn.x - snap_payload, 0).astype(
+            # Dense one-hot arithmetic instead of scalar gathers / scatters /
+            # conds: under vmap those lower to serial per-batch-element loops
+            # (or both-branch selects), which destroys the batched-scan
+            # throughput; elementwise (K,) ops stay fully vectorised.
+            onehot = jnp.arange(k, dtype=jnp.int32) == server
+            if has_pull:
+                # The balancer spends one token on every routed arrival (it
+                # cannot see FIFO drops); an empty selected pool is a token
+                # miss -- the uniform-random fallback path.
+                tok_sel = jnp.sum(jnp.where(onehot, c.tokens, 0))
+                token_miss = c.token_miss + (arr & (tok_sel == 0)).astype(
                     jnp.int32
                 )
-            tokens = jnp.where(snap_mask, fresh, tokens)
-            token_sum = c.token_sum + jnp.where(
-                act, jnp.sum(tokens), 0
-            ).astype(jnp.int32)
-        else:
-            token_sum = c.token_sum
+                tokens = jnp.maximum(
+                    c.tokens - (onehot & arr).astype(jnp.int32), 0
+                )
+            else:
+                token_miss = c.token_miss
+                tokens = c.tokens
+            q_sel = jnp.sum(jnp.where(onehot, c.q_true, 0))
+            # A full FIFO drops the arrival (counted) rather than letting the
+            # tail wrap onto the live head entry.
+            admit = arr & (q_sel < b)
+            dropped = c.dropped + (arr & ~admit).astype(jnp.int32)
+            sel = onehot & admit
+            head_sel = jnp.sum(jnp.where(onehot, c.head_ptr, 0))
+            tail = (head_sel + q_sel) % b
+            # Masked one-element scatter (the ring itself still needs
+            # indexing).
+            buf_jid = c.buf_jid.at[server, tail].set(
+                jnp.where(admit, jid, c.buf_jid[server, tail])
+            )
+            q_true = c.q_true + sel.astype(jnp.int32)
+            head_rem = jnp.where(sel & (c.q_true == 0), size, c.head_rem)
+            emu = approx_lib.emu_arrival_masked(c.emu, sel, acfg)
+            arrs = c.arrs + admit.astype(jnp.int32)
+            per_srv = c.per_srv + sel.astype(jnp.int32)
+
+        # --- 2. service ------------------------------------------------
+        with jax.named_scope("service"):
+            # Past the cell's horizon (act False) nothing serves: the mask
+            # freezes head_rem / q_true / deps exactly where the horizon left
+            # them.  `act & True` is the identity, so unpadded runs are
+            # bit-identical to the historical unmasked program.
+            busy = (q_true > 0) & act
+            if rates is None:
+                units = None
+                if has_fault:
+                    eff_units = workload_lib.faulted_service_units(
+                        jid, faulted, jnp.ones((k,), jnp.int32),
+                        static.fault, scn.slow_factor,
+                    )
+                    head_rem = jnp.where(busy, head_rem - eff_units, head_rem)
+                else:
+                    head_rem = jnp.where(busy, head_rem - 1, head_rem)
+            else:
+                units = workload_lib.service_units(jid, rates)
+                if has_fault:
+                    eff_units = workload_lib.faulted_service_units(
+                        jid, faulted, units, static.fault, scn.slow_factor,
+                        rates=rates,
+                    )
+                else:
+                    eff_units = units
+                head_rem = jnp.where(busy, head_rem - eff_units, head_rem)
+            dep = busy & (head_rem <= 0)
+            departed_jid = jnp.where(
+                dep, buf_jid[jnp.arange(k), c.head_ptr % b], -1
+            )
+            q_true = jnp.where(dep, q_true - 1, q_true)
+            head_ptr = jnp.where(dep, c.head_ptr + 1, c.head_ptr)
+            # Promote the next job (if any) into service with its true size.
+            next_jid = buf_jid[jnp.arange(k), head_ptr % b]
+            next_size = sizes[jnp.clip(next_jid, 0, sizes.shape[0] - 1)]
+            head_rem = jnp.where(dep & (q_true > 0), next_size, head_rem)
+            deps = c.deps + jnp.sum(dep, dtype=jnp.int32)
+
+        # --- 3. emulation drain -----------------------------------------
+        with jax.named_scope("drain"):
+            emu = approx_lib.emu_drain_slot(emu, acfg, units=units, active=act)
+
+        # --- 4/5. communication trigger (shared core, comm.py) ----------
+        with jax.named_scope("trigger"):
+            # The trigger counters (slots_since_msg in particular) must freeze
+            # past the horizon, or RT/ET+RT cells would keep messaging through
+            # the padding; evaluate unconditionally, then select the advanced
+            # state only on active slots (the identity when act is True).
+            err = approx_lib.approximation_error(emu, q_true)
+            # Crashed servers cannot send (their counters keep advancing, so
+            # the first healthy slot re-fires); a recovery force-sends a
+            # resync.  The emulation keeps draining with *nominal* units --
+            # the balancer is fault-unaware, so a crash or slowdown grows the
+            # error until the trigger or the staleness timeout reacts.
+            if has_fault and static.fault == "crash":
+                can_send, force = ~faulted, recovered
+            else:
+                can_send = force = None
+            triggered, comm_adv = comm_lib.evaluate(
+                c.comm, ccfg, err, dep.astype(jnp.int32),
+                can_send=can_send, force=force, q=q_true,
+                count_msgs=not has_net,
+            )
+            triggered = triggered & act
+            if has_ack:
+                # The ack/keepalive channels draw from a third child of the
+                # per-slot net key, so the fire_forget two-way split -- and
+                # with it every pre-existing sample path -- stays byte-stable.
+                kd, kj, ka = jax.random.split(nkey, 3)
+                delivered, payload, sent, net_adv = comm_lib.net_step_ack(
+                    c.net, ncfg, triggered, q_true,
+                    jax.random.uniform(kd, (k,), jnp.float32),
+                    jax.random.uniform(kj, (k,), jnp.float32),
+                    jax.random.uniform(ka, (4, k), jnp.float32),
+                    can_send=can_send,
+                )
+            elif has_net:
+                # can_send wipes a crashed server's queued piggyback so it
+                # cannot send its pre-crash snapshot at the next free slot --
+                # the recovery resync (force) is the re-announcement path.
+                kd, kj = jax.random.split(nkey)
+                delivered, payload, sent, net_adv = comm_lib.net_step(
+                    c.net, ncfg, triggered, q_true,
+                    jax.random.uniform(kd, (k,), jnp.float32),
+                    jax.random.uniform(kj, (k,), jnp.float32),
+                    can_send=can_send,
+                )
+            if has_net:
+                delivered = delivered & act
+                net_state = jax.tree.map(
+                    lambda adv, old: jnp.where(act, adv, old), net_adv, c.net
+                )
+                # net_step owns wire accounting (piggybacking batches queued
+                # triggers into one send).
+                comm_adv = comm_lib.CommState(
+                    deps_since_msg=comm_adv.deps_since_msg,
+                    slots_since_msg=comm_adv.slots_since_msg,
+                    msgs=comm_adv.msgs + jnp.where(act, sent, 0),
+                )
+                snap_mask, snap_payload = delivered, payload
+            else:
+                net_state = c.net
+                snap_mask, snap_payload = triggered, q_true
+            if has_net and static.policy in ("sq2", "sqd"):
+                # SQ(d)'s query implementation costs 2d messages per offered
+                # arrival (d probes + d replies), now counted as real traffic
+                # on the same axis as the push-based schemes.  The probes ride
+                # the same network: their staleness is the q_hist ring above
+                # (they are not subject to loss -- a query that must be
+                # re-issued would stall the arrival, so d is effectively the
+                # retry budget).
+                d_q = 2 if static.policy == "sq2" else static.sqd
+                comm_adv = comm_lib.CommState(
+                    deps_since_msg=comm_adv.deps_since_msg,
+                    slots_since_msg=comm_adv.slots_since_msg,
+                    msgs=comm_adv.msgs + 2 * d_q * arr.astype(jnp.int32),
+                )
+            comm_state = jax.tree.map(
+                lambda adv, old: jnp.where(act, adv, old), comm_adv, c.comm
+            )
+            emu = approx_lib.emu_message_reset(
+                emu, snap_payload, snap_mask, acfg
+            )
+            if has_pull:
+                # A delivered token message overwrites that server's pool
+                # entry from the queue snapshot it carried: 1 iff idle for
+                # JIQ, the headroom below the threshold for hsq.  Stale
+                # tokens of a crashed server are spent and never refreshed,
+                # which is what bounds its misroutes.
+                if static.comm == "jiq":
+                    fresh = (snap_payload == 0).astype(jnp.int32)
+                else:  # hsq
+                    fresh = jnp.maximum(scn.x - snap_payload, 0).astype(
+                        jnp.int32
+                    )
+                tokens = jnp.where(snap_mask, fresh, tokens)
+                token_sum = c.token_sum + jnp.where(
+                    act, jnp.sum(tokens), 0
+                ).astype(jnp.int32)
+            else:
+                token_sum = c.token_sum
 
         # --- 6. metrics ---------------------------------------------------
-        if stale_ring:
-            q_hist = c.q_hist.at[jid % cap].set(
-                jnp.where(act, q_true, c.q_hist[jid % cap])
+        with jax.named_scope("metrics"):
+            if stale_ring:
+                q_hist = c.q_hist.at[jid % cap].set(
+                    jnp.where(act, q_true, c.q_hist[jid % cap])
+                )
+            else:
+                q_hist = c.q_hist
+            aq = jnp.max(jnp.abs(q_true - emu.q_app))
+            gap = jnp.max(q_true) - jnp.min(q_true)
+            carry = _Carry(
+                q_true=q_true,
+                head_rem=head_rem,
+                buf_jid=buf_jid,
+                head_ptr=head_ptr,
+                emu=emu,
+                comm=comm_state,
+                rr_ptr=rr_ptr,
+                deps=deps,
+                arrs=arrs,
+                dropped=dropped,
+                per_srv=per_srv,
+                max_aq=jnp.maximum(c.max_aq, aq),
+                max_q=jnp.maximum(c.max_q, jnp.max(q_true)),
+                gap_sup=jnp.maximum(c.gap_sup, gap),
+                fault_state=faulted,
+                net=net_state,
+                q_hist=q_hist,
+                tokens=tokens,
+                token_miss=token_miss,
+                token_sum=token_sum,
             )
-        else:
-            q_hist = c.q_hist
-        aq = jnp.max(jnp.abs(q_true - emu.q_app))
-        gap = jnp.max(q_true) - jnp.min(q_true)
-        carry = _Carry(
-            q_true=q_true,
-            head_rem=head_rem,
-            buf_jid=buf_jid,
-            head_ptr=head_ptr,
-            emu=emu,
-            comm=comm_state,
-            rr_ptr=rr_ptr,
-            deps=deps,
-            arrs=arrs,
-            dropped=dropped,
-            per_srv=per_srv,
-            max_aq=jnp.maximum(c.max_aq, aq),
-            max_q=jnp.maximum(c.max_q, jnp.max(q_true)),
-            gap_sup=jnp.maximum(c.gap_sup, gap),
-            fault_state=faulted,
-            net=net_state,
-            q_hist=q_hist,
-            tokens=tokens,
-            token_miss=token_miss,
-            token_sum=token_sum,
-        )
         return carry, departed_jid
 
     t = arrive.shape[0]
@@ -1053,14 +1070,15 @@ def _sim_core(
     final, departed = jax.lax.scan(slot, init, xs)
 
     # completion slot per job id (-1 if never completed).
-    comp_slot = jnp.full((t,), -1, jnp.int32)
-    slot_idx = jnp.broadcast_to(
-        jnp.arange(t, dtype=jnp.int32)[:, None], departed.shape
-    )
-    valid = departed >= 0
-    comp_slot = comp_slot.at[jnp.where(valid, departed, 0)].max(
-        jnp.where(valid, slot_idx, -1)
-    )
+    with jax.named_scope("complete"):
+        comp_slot = jnp.full((t,), -1, jnp.int32)
+        slot_idx = jnp.broadcast_to(
+            jnp.arange(t, dtype=jnp.int32)[:, None], departed.shape
+        )
+        valid = departed >= 0
+        comp_slot = comp_slot.at[jnp.where(valid, departed, 0)].max(
+            jnp.where(valid, slot_idx, -1)
+        )
     out = (
         comp_slot,
         final.comm.msgs,
@@ -1085,7 +1103,8 @@ def _sim_core(
 
 def _run_one(key, scn: Scenario, static: StaticConfig):
     """Workload draw + scan for one (key, scenario) pair; vmap-able."""
-    prep = _prep(key, static, scn)
+    with jax.named_scope("draw"):
+        prep = _prep(key, static, scn)
     arrive, sizes, slot_keys, act = prep[:4]
     rest = list(prep[4:])
     classes = rest.pop(0) if static.classes > 1 else None
@@ -1573,17 +1592,36 @@ def simulate_grid(
       bit-identical to ``simulate(key_s, cell_c)`` (asserted by
       ``tests/test_grid.py``): vmap, shard_map and padding are all
       semantics-preserving.
+
+    The call is the host span ``simulate_grid`` (count ``runs``), whose
+    children are ``.prepare`` (:func:`grid_program`), ``.run`` (the
+    device program, waited for), ``.fetch`` (the outputs to the host,
+    count ``bytes``) and ``.finalize`` (:class:`SimResult` per run, count
+    ``jobs``, the completion times produced).
     """
-    fn, args, (c, s) = grid_program(keys, static_cfg, scenarios, shard=shard)
-    out_np = [np.asarray(o)[: c * s] for o in fn(*args)]
-    arrive, rest = out_np[0], out_np[1:]
-    return [
-        [
-            _finalize(arrive[i * s + j], tuple(o[i * s + j] for o in rest))
-            for j in range(s)
-        ]
-        for i in range(c)
-    ]
+    with spans.span("simulate_grid") as root:
+        with spans.span("simulate_grid.prepare"):
+            fn, args, (c, s) = grid_program(
+                keys, static_cfg, scenarios, shard=shard
+            )
+        root.count(runs=c * s)
+        with spans.span("simulate_grid.run"):
+            out = jax.block_until_ready(fn(*args))
+        with spans.span("simulate_grid.fetch",
+                        bytes=sum(o.nbytes for o in out)):
+            out_np = [np.asarray(o)[: c * s] for o in out]
+        arrive, rest = out_np[0], out_np[1:]
+        with spans.span("simulate_grid.finalize") as fin:
+            results = [
+                [
+                    _finalize(arrive[i * s + j],
+                              tuple(o[i * s + j] for o in rest))
+                    for j in range(s)
+                ]
+                for i in range(c)
+            ]
+            fin.count(jobs=sum(r.jct.size for row in results for r in row))
+    return results
 
 
 def simulate_batch(

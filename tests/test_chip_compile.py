@@ -13,6 +13,7 @@ The topology is described inside a module fixture (never at import, in a
 library, so only the worker that runs this file does.
 """
 import pathlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -133,7 +134,10 @@ def test_care_route_refuses_more_than_scoped_vmem():
 # --- chip_smoke.py's entry programs at its sizes -----------------------------
 
 
-def test_paper_grid_program_fits_one_chip(one_chip):
+@pytest.fixture(scope="module")
+def paper_grid(one_chip):
+    """The paper grid's program as compiled for one chip, and its runs
+    and slots."""
     cs = _smoke()
     size = cs.paper_grid.__kwdefaults__
     cells = cs._paper_cells(size["slots"], size["loads"], size["xs"])
@@ -141,8 +145,82 @@ def test_paper_grid_program_fits_one_chip(one_chip):
         list(range(size["seeds"])), cells[0].static_part(),
         [x.scenario() for x in cells],
     )
-    assert (c * s, size["slots"]) == (96, 100_000)
-    assert _bytes(_compile(fn, args, one_chip)) < HBM_BYTES
+    return _compile(fn, args, one_chip), c * s, size["slots"]
+
+
+def test_paper_grid_program_fits_one_chip(paper_grid):
+    compiled, runs, slots = paper_grid
+    assert (runs, slots) == (96, 100_000)
+    assert _bytes(compiled) < HBM_BYTES
+
+
+# The slot step's phase scopes (``slotted_sim._sim_core``), in order, and
+# what the scan itself puts in its body: the slicing of ``xs``, the writing
+# of ``ys``, the loop counter, and broadcasts hoisted to the body's call.
+PHASES = ("faults", "route", "service", "drain", "trigger", "metrics")
+SCAN_PLUMBING = {"dynamic_slice", "squeeze", "broadcast_in_dim",
+                 "dynamic_update_slice", "add", "closed_call"}
+_INSTR = re.compile(r"(?:ROOT )?(%[\w.\-]+) = .*? ([a-z][\w\-]*)\(")
+
+
+def _computations(text: str):
+    """``{computation: [(opcode, op_name or None, line)]}`` of an HLO
+    module's text, in schedule order, and the entry's name."""
+    comps, entry, cur = {}, None, None
+    for line in text.splitlines():
+        head = re.match(r"(ENTRY )?%([\w.\-]+) .*\{$", line)
+        if head:
+            cur = comps[head.group(2)] = []
+            entry = head.group(2) if head.group(1) else entry
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None and (m := _INSTR.match(line.strip())):
+            op = re.search(r'op_name="([^"]*)"', line)
+            cur.append((m.group(2), op and op.group(1), line))
+    return comps, entry
+
+
+def _with_fusions(comps, instrs):
+    """``instrs`` and those of the fused computations they call."""
+    for inst in instrs:
+        yield inst
+        for callee in re.findall(r"calls=%([\w.\-]+)", inst[2]):
+            yield from _with_fusions(comps, comps[callee])
+
+
+def _scope(op_name, names):
+    """The first of ``names`` that is a component of ``op_name`` (under
+    ``vmap`` a scope reads ``vmap(<name>)``)."""
+    for part in (op_name or "").split("/"):
+        for name in names:
+            if part in (name, f"vmap({name})"):
+                return name
+    return None
+
+
+def test_paper_grid_phases_own_the_scan_body(paper_grid):
+    comps, entry = _computations(paper_grid[0].as_text())
+    at = next(i for i, inst in enumerate(comps[entry]) if inst[0] == "while")
+    body = re.search(r"body=%([\w.\-]+)", comps[entry][at][2]).group(1)
+    owned = {p: 0 for p in PHASES}
+    for opcode, op_name, line in _with_fusions(comps, comps[body]):
+        if op_name is None or opcode == "constant":
+            # Made by the compiler, or a constant, which keeps the name of
+            # whichever of its equal copies the compiler kept.
+            continue
+        phase = _scope(op_name, PHASES)
+        if phase is None:
+            assert op_name.rsplit("/while/body/", 1)[-1] in SCAN_PLUMBING, line
+        else:
+            owned[phase] += 1
+    # The paper's cells run no fault model (fault "none").
+    assert owned.pop("faults") == 0
+    assert all(n > 0 for n in owned.values()), owned
+    before = list(_with_fusions(comps, comps[entry][:at]))
+    after = list(_with_fusions(comps, comps[entry][at + 1:]))
+    assert any(_scope(op, ["draw"]) for _, op, _ in before)
+    assert any(_scope(op, ["complete"]) and "scatter" in op
+               for _, op, _ in after)
 
 
 def test_serving_grid_program_fits_one_chip(one_chip):

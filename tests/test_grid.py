@@ -27,6 +27,7 @@ import jax
 import numpy as np
 import pytest
 
+from repro import spans
 from repro.core import SimConfig, simulate, simulate_batch, simulate_grid
 from repro.core.care import slotted_sim
 from repro.core.dispatch_sim import DispatchSimConfig, dispatch_batch
@@ -173,6 +174,42 @@ class TestSimulateGrid:
         grid = simulate_grid([1], static, [c.scenario() for c in cfgs])
         for cell, cfg in zip(grid, cfgs):
             _assert_same(cell[0], simulate(jax.random.key(1), cfg))
+
+
+class TestGridSpans:
+    """One call's host spans (``repro.spans``): its four phases and the
+    counts that are their bases."""
+
+    CHILDREN = ("prepare", "run", "fetch", "finalize")
+
+    @pytest.fixture(scope="class")
+    def call(self):
+        static = GRID_CFGS[0].static_part()
+        scns = [c.scenario() for c in GRID_CFGS]
+        grid = simulate_grid(list(GRID_SEEDS), static, scns)
+        fn, args, _ = slotted_sim.grid_program(list(GRID_SEEDS), static, scns)
+        out_bytes = sum(
+            int(np.prod(o.shape)) * o.dtype.itemsize
+            for o in jax.eval_shape(fn, *args)
+        )
+        return grid, spans.last("simulate_grid"), out_bytes
+
+    def test_root_holds_the_four_children(self, call):
+        _, rec, _ = call
+        names = {f"simulate_grid.{c}" for c in self.CHILDREN}
+        assert set(rec.children) == names
+        assert all(rec.children[n] > 0 for n in names)
+        assert sum(rec.children.values()) <= rec.seconds
+
+    @pytest.mark.parametrize("count", ["runs", "bytes", "jobs"])
+    def test_count_is_its_base(self, call, count):
+        grid, rec, out_bytes = call
+        want = {
+            "runs": len(GRID_CFGS) * len(GRID_SEEDS),
+            "bytes": out_bytes,
+            "jobs": sum(len(r.jct) for cell in grid for r in cell),
+        }[count]
+        assert rec.counts[count] == want
 
 
 # ---------------------------------------------------------------------------
