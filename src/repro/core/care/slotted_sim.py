@@ -81,9 +81,25 @@ program is bit-identical to the historical compile-per-cell program
 (golden-tested in ``tests/test_grid.py``).
 
 The whole simulation is a single ``jax.lax.scan``; all per-server state is
-vectorised and job FIFOs are circular buffers carried through the scan, so
-the simulator jit-compiles **once per StaticConfig** and runs at native
-speed on CPU/TPU.  Batching entry points:
+vectorised, so the simulator jit-compiles **once per StaticConfig** and
+runs at native speed on CPU/TPU.  How a server's FIFO is carried depends
+on the static kinds:
+
+* At unit rate with no fault model (no ``service_rates``, ``fault="none"``)
+  every server works one unit per slot, so a job's departure slot follows
+  from Lindley's recursion when it is admitted.  The scan keeps a per-run
+  departure *calendar* (one bit per server and slot) that admission
+  writes and step 2 reads at the slot index, and emits each admitted
+  job's completion slot as its per-slot output.  No per-job state is
+  gathered inside the loop and nothing is scattered after it.
+* Otherwise (the credit schedule of heterogeneous rates, crash or slow
+  faults) the work per slot varies: job FIFOs are circular buffers of job
+  ids, the head job counts down its remaining size, and the completion
+  slot of each departed id is scattered after the scan.
+
+``buffer_cap`` bounds admission on both paths (a full FIFO drops the
+arrival); only the ring path also uses it as an array shape.  Batching
+entry points:
 
 * :func:`simulate` -- one key, one cell.
 * :func:`simulate_batch` -- vmap over a batch of PRNG keys for one cell.
@@ -97,8 +113,10 @@ The compiled program names its phases with ``jax.named_scope``, so that a
 device trace's operations map back to them through the executable's
 ``op_name`` metadata: ``draw`` (the workload draw), ``faults``, ``route``,
 ``service``, ``drain``, ``trigger`` and ``metrics`` (the slot step, in
-order) and ``complete`` (the completion-slot scatter after the scan).
-:func:`simulate_grid` also records host spans (:mod:`repro.spans`).
+order) and, on the ring path, ``complete`` (the completion-slot scatter
+after the scan).  :func:`simulate_grid` also records host spans
+(:mod:`repro.spans`); its root span counts ``calendar_runs``, the runs
+that took the calendar path.
 """
 from __future__ import annotations
 
@@ -139,6 +157,8 @@ class StaticConfig:
     policy: routing_lib.PolicyKind = "jsaq"
     comm: CommKind = "et"
     approx: approx_lib.ApproxKind = "msr"
+    # Per-server FIFO capacity: an arrival at a full FIFO is dropped.  An
+    # array shape only on the job-ring path (module docstring).
     buffer_cap: int = 2048
     sqd: int = 2
     arrival: str = "bernoulli"  # "bernoulli" | "mmpp"
@@ -574,9 +594,11 @@ class SimResult:
 @dataclasses.dataclass
 class _Carry:
     q_true: jnp.ndarray  # (K,) true queue lengths
-    head_rem: jnp.ndarray  # (K,) remaining slots of in-service job
-    buf_jid: jnp.ndarray  # (K, B) circular FIFO of job ids (arrival slots)
-    head_ptr: jnp.ndarray  # (K,) FIFO head index
+    # The job ring and the head job's countdown; None on the calendar path
+    # (see _uses_calendar), which carries last_dep and cal instead.
+    head_rem: Optional[jnp.ndarray]  # (K,) remaining slots of in-service job
+    buf_jid: Optional[jnp.ndarray]  # (K, B) FIFO ring of job ids (arrival slots)
+    head_ptr: Optional[jnp.ndarray]  # (K,) FIFO head index
     emu: approx_lib.EmuState
     comm: comm_lib.CommState  # shared trigger bookkeeping + message total
     rr_ptr: jnp.ndarray  # () round-robin pointer
@@ -600,11 +622,31 @@ class _Carry:
     tokens: Optional[jnp.ndarray] = None  # (K,) i32 balancer token pool
     token_miss: Optional[jnp.ndarray] = None  # () i32 empty-pool routings
     token_sum: Optional[jnp.ndarray] = None  # () i32 summed pool occupancy
+    # Departure calendar (calendar path only): the departure slot of each
+    # server's last admitted job, and one bit per server of the servers
+    # departing in each slot, W = ceil(K / 32) words per slot.
+    last_dep: Optional[jnp.ndarray] = None  # (K,) i32
+    cal: Optional[jnp.ndarray] = None  # (T * W,) u32
 
 
 jax.tree_util.register_dataclass(
     _Carry, data_fields=[f.name for f in dataclasses.fields(_Carry)], meta_fields=[]
 )
+
+
+def _uses_calendar(static: StaticConfig) -> bool:
+    """Whether the slot step finds departures in a calendar.
+
+    A server that works one unit in every slot, as at unit rate with no
+    fault model, serves a FIFO by Lindley's recursion: a job of size ``S``
+    admitted in slot ``t`` departs in slot ``max(t, D + 1) + S - 1``, where
+    ``D`` is the departure slot of the job admitted before it.  Admission
+    then knows the departure, so the scan needs no job ring.  Otherwise
+    the work per slot varies (the credit schedule of
+    ``workload.service_units``, crash and slow faults) and the ring's
+    countdown stays.
+    """
+    return not static.use_rates and static.fault == "none"
 
 
 def _prep(key: jax.Array, static: StaticConfig, scn: Scenario):
@@ -740,6 +782,11 @@ def _sim_core(
     else:
         rates = None
         drain_slots = None
+    t = arrive.shape[0]
+    calendar = _uses_calendar(static)
+    # The calendar keeps one word of departure bits per slot and per 32
+    # servers, flat so that a run's calendar is one long lane axis.
+    words = -(-k // 32)
 
     def slot(c: _Carry, xs):
         arr, size, jid, skey, act = xs[:5]
@@ -843,57 +890,89 @@ def _sim_core(
             admit = arr & (q_sel < b)
             dropped = c.dropped + (arr & ~admit).astype(jnp.int32)
             sel = onehot & admit
-            head_sel = jnp.sum(jnp.where(onehot, c.head_ptr, 0))
-            tail = (head_sel + q_sel) % b
-            # Masked one-element scatter (the ring itself still needs
-            # indexing).
-            buf_jid = c.buf_jid.at[server, tail].set(
-                jnp.where(admit, jid, c.buf_jid[server, tail])
-            )
-            q_true = c.q_true + sel.astype(jnp.int32)
-            head_rem = jnp.where(sel & (c.q_true == 0), size, c.head_rem)
+            if calendar:
+                # Lindley's recursion (_uses_calendar): the job starts when
+                # it arrives or after the job before it departs.  A size
+                # past the scan cannot depart in it; capping it there keeps
+                # the sum in int32.
+                last_sel = jnp.sum(jnp.where(onehot, c.last_dep, 0))
+                d = (jnp.maximum(jid, last_sel + 1)
+                     + jnp.minimum(size, t + 1) - 1)
+                last_dep = jnp.where(sel, jnp.minimum(d, t), c.last_dep)
+                # One bit per departure, added: two servers of one word may
+                # depart in the same slot.  A departure past the scan is
+                # written nowhere (the index is out of range).
+                word_at = jnp.where(admit & (d < t),
+                                    d * words + server // 32, t * words)
+                bit = jnp.left_shift(jnp.uint32(1),
+                                     server.astype(jnp.uint32) % 32)
+                cal = c.cal.at[word_at].add(bit, mode="drop")
+                comp = jnp.where(admit & (d < scn.horizon) & (d < t), d, -1)
+                buf_jid = head_rem = None
+                q_true = c.q_true + sel.astype(jnp.int32)
+            else:
+                head_sel = jnp.sum(jnp.where(onehot, c.head_ptr, 0))
+                tail = (head_sel + q_sel) % b
+                # Masked one-element scatter (the ring itself still needs
+                # indexing).
+                buf_jid = c.buf_jid.at[server, tail].set(
+                    jnp.where(admit, jid, c.buf_jid[server, tail])
+                )
+                q_true = c.q_true + sel.astype(jnp.int32)
+                head_rem = jnp.where(sel & (c.q_true == 0), size, c.head_rem)
+                last_dep = cal = None
             emu = approx_lib.emu_arrival_masked(c.emu, sel, acfg)
             arrs = c.arrs + admit.astype(jnp.int32)
             per_srv = c.per_srv + sel.astype(jnp.int32)
 
         # --- 2. service ------------------------------------------------
         with jax.named_scope("service"):
-            # Past the cell's horizon (act False) nothing serves: the mask
-            # freezes head_rem / q_true / deps exactly where the horizon left
-            # them.  `act & True` is the identity, so unpadded runs are
-            # bit-identical to the historical unmasked program.
-            busy = (q_true > 0) & act
-            if rates is None:
-                units = None
-                if has_fault:
+            if calendar:
+                # The slot's word, read at the loop counter (one index for
+                # every run of a batch), spread to one bit per server.
+                # Past the horizon (act False) nothing departs.
+                word = jax.lax.dynamic_slice(cal, (jid * words,), (words,))
+                bits = jnp.broadcast_to(word[:, None], (words, 32)).reshape(-1)
+                shift = jnp.arange(k, dtype=jnp.uint32) % 32
+                dep = act & ((bits[:k] >> shift) & 1 == 1)
+                q_true = jnp.where(dep, q_true - 1, q_true)
+                deps = c.deps + jnp.sum(dep, dtype=jnp.int32)
+                units = head_ptr = None
+                out_t = comp
+            else:
+                # Past the cell's horizon (act False) nothing serves: the mask
+                # freezes head_rem / q_true / deps exactly where the horizon left
+                # them.  `act & True` is the identity, so unpadded runs are
+                # bit-identical to the historical unmasked program.
+                busy = (q_true > 0) & act
+                if rates is None:  # then a fault model is on
+                    units = None
                     eff_units = workload_lib.faulted_service_units(
                         jid, faulted, jnp.ones((k,), jnp.int32),
                         static.fault, scn.slow_factor,
                     )
-                    head_rem = jnp.where(busy, head_rem - eff_units, head_rem)
                 else:
-                    head_rem = jnp.where(busy, head_rem - 1, head_rem)
-            else:
-                units = workload_lib.service_units(jid, rates)
-                if has_fault:
-                    eff_units = workload_lib.faulted_service_units(
-                        jid, faulted, units, static.fault, scn.slow_factor,
-                        rates=rates,
-                    )
-                else:
-                    eff_units = units
+                    units = workload_lib.service_units(jid, rates)
+                    if has_fault:
+                        eff_units = workload_lib.faulted_service_units(
+                            jid, faulted, units, static.fault, scn.slow_factor,
+                            rates=rates,
+                        )
+                    else:
+                        eff_units = units
                 head_rem = jnp.where(busy, head_rem - eff_units, head_rem)
-            dep = busy & (head_rem <= 0)
-            departed_jid = jnp.where(
-                dep, buf_jid[jnp.arange(k), c.head_ptr % b], -1
-            )
-            q_true = jnp.where(dep, q_true - 1, q_true)
-            head_ptr = jnp.where(dep, c.head_ptr + 1, c.head_ptr)
-            # Promote the next job (if any) into service with its true size.
-            next_jid = buf_jid[jnp.arange(k), head_ptr % b]
-            next_size = sizes[jnp.clip(next_jid, 0, sizes.shape[0] - 1)]
-            head_rem = jnp.where(dep & (q_true > 0), next_size, head_rem)
-            deps = c.deps + jnp.sum(dep, dtype=jnp.int32)
+                dep = busy & (head_rem <= 0)
+                departed_jid = jnp.where(
+                    dep, buf_jid[jnp.arange(k), c.head_ptr % b], -1
+                )
+                q_true = jnp.where(dep, q_true - 1, q_true)
+                head_ptr = jnp.where(dep, c.head_ptr + 1, c.head_ptr)
+                # Promote the next job (if any) into service with its true size.
+                next_jid = buf_jid[jnp.arange(k), head_ptr % b]
+                next_size = sizes[jnp.clip(next_jid, 0, sizes.shape[0] - 1)]
+                head_rem = jnp.where(dep & (q_true > 0), next_size, head_rem)
+                deps = c.deps + jnp.sum(dep, dtype=jnp.int32)
+                out_t = departed_jid
 
         # --- 3. emulation drain -----------------------------------------
         with jax.named_scope("drain"):
@@ -1030,15 +1109,16 @@ def _sim_core(
                 tokens=tokens,
                 token_miss=token_miss,
                 token_sum=token_sum,
+                last_dep=last_dep,
+                cal=cal,
             )
-        return carry, departed_jid
+        return carry, out_t
 
-    t = arrive.shape[0]
     init = _Carry(
         q_true=jnp.zeros((k,), jnp.int32),
-        head_rem=jnp.zeros((k,), jnp.int32),
-        buf_jid=jnp.full((k, b), -1, jnp.int32),
-        head_ptr=jnp.zeros((k,), jnp.int32),
+        head_rem=None if calendar else jnp.zeros((k,), jnp.int32),
+        buf_jid=None if calendar else jnp.full((k, b), -1, jnp.int32),
+        head_ptr=None if calendar else jnp.zeros((k,), jnp.int32),
         emu=approx_lib.EmuState.init(jnp.zeros((k,), jnp.int32), acfg),
         comm=comm_lib.CommState.init(k),
         rr_ptr=jnp.zeros((), jnp.int32),
@@ -1059,6 +1139,8 @@ def _sim_core(
         tokens=jnp.zeros((k,), jnp.int32) if has_pull else None,
         token_miss=jnp.zeros((), jnp.int32) if has_pull else None,
         token_sum=jnp.zeros((), jnp.int32) if has_pull else None,
+        last_dep=jnp.full((k,), -1, jnp.int32) if calendar else None,
+        cal=jnp.zeros((t * words,), jnp.uint32) if calendar else None,
     )
     xs = (arrive, sizes, jnp.arange(t, dtype=jnp.int32), slot_keys, active)
     if has_cls:
@@ -1067,18 +1149,23 @@ def _sim_core(
         xs += (net_keys,)
     if has_fault:
         xs += (fault_keys,)
-    final, departed = jax.lax.scan(slot, init, xs)
+    final, ys = jax.lax.scan(slot, init, xs)
 
     # completion slot per job id (-1 if never completed).
-    with jax.named_scope("complete"):
-        comp_slot = jnp.full((t,), -1, jnp.int32)
-        slot_idx = jnp.broadcast_to(
-            jnp.arange(t, dtype=jnp.int32)[:, None], departed.shape
-        )
-        valid = departed >= 0
-        comp_slot = comp_slot.at[jnp.where(valid, departed, 0)].max(
-            jnp.where(valid, slot_idx, -1)
-        )
+    if calendar:
+        # Each slot's job knew its completion slot when it was admitted.
+        comp_slot = ys
+    else:
+        departed = ys
+        with jax.named_scope("complete"):
+            comp_slot = jnp.full((t,), -1, jnp.int32)
+            slot_idx = jnp.broadcast_to(
+                jnp.arange(t, dtype=jnp.int32)[:, None], departed.shape
+            )
+            valid = departed >= 0
+            comp_slot = comp_slot.at[jnp.where(valid, departed, 0)].max(
+                jnp.where(valid, slot_idx, -1)
+            )
     out = (
         comp_slot,
         final.comm.msgs,
@@ -1604,7 +1691,9 @@ def simulate_grid(
             fn, args, (c, s) = grid_program(
                 keys, static_cfg, scenarios, shard=shard
             )
-        root.count(runs=c * s)
+        calendar = (static_cfg.route_backend == "dense"
+                    and _uses_calendar(static_cfg))
+        root.count(runs=c * s, calendar_runs=c * s if calendar else 0)
         with spans.span("simulate_grid.run"):
             out = jax.block_until_ready(fn(*args))
         with spans.span("simulate_grid.fetch",

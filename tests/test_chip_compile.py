@@ -219,8 +219,16 @@ def test_paper_grid_phases_own_the_scan_body(paper_grid):
     before = list(_with_fusions(comps, comps[entry][:at]))
     after = list(_with_fusions(comps, comps[entry][at + 1:]))
     assert any(_scope(op, ["draw"]) for _, op, _ in before)
-    assert any(_scope(op, ["complete"]) and "scatter" in op
-               for _, op, _ in after)
+    # Unit rates and no fault model: departures come from the calendar,
+    # read at the loop counter, so the loop gathers nothing from the job
+    # ring or the sizes (a gather of the calendar's word would hold one
+    # index per run), and completion slots are the loop's own output, so
+    # no scatter or sort follows it.
+    runs = paper_grid[1]
+    for opcode, _, line in _with_fusions(comps, comps[body]):
+        if opcode == "gather":
+            assert re.search(rf"= u32\[{runs}(,1)?\]", line), line
+    assert not any(opcode in ("scatter", "sort") for opcode, _, _ in after)
 
 
 def test_serving_grid_program_fits_one_chip(one_chip):

@@ -176,6 +176,51 @@ class TestSimulateGrid:
             _assert_same(cell[0], simulate(jax.random.key(1), cfg))
 
 
+ALL_K30 = tuple([True] * 30)
+CALENDAR_CFGS = {
+    "geometric": SimConfig(slots=1500, load=0.95, x=3),
+    "deterministic": SimConfig(slots=1500, load=0.9, service="deterministic",
+                               mean_service=20),
+    "pareto": SimConfig(slots=1500, load=0.9, service="pareto",
+                        service_tail=1.6, mean_service=20),
+    "weibull": SimConfig(slots=1500, load=0.9, service="weibull",
+                         service_tail=0.8, mean_service=20),
+    "mmpp": SimConfig(slots=1500, load=0.95, arrival="mmpp",
+                      burst_intensity=1.7, burst_stay=0.97),
+    "padded": SimConfig(slots=1000, max_slots=1500, load=0.95),
+    "drops": SimConfig(slots=1500, load=0.99, servers=4, mean_service=8,
+                       buffer_cap=4),
+    "net": SimConfig(slots=1500, load=0.9, network="net", net_delay=2,
+                     net_jitter=1, net_drop=0.1),
+    "classes": SimConfig(slots=1500, load=0.9, class_mix=(0.7, 0.3),
+                         class_affinity=(ALL_K30, ALL_K30[:10] + (False,) * 20)),
+    "jiq": SimConfig(slots=1500, load=0.9, policy="jiq", comm="jiq"),
+    "k40": SimConfig(slots=1500, load=0.95, servers=40, mean_service=40),
+    "k64": SimConfig(slots=1500, load=0.95, servers=64, mean_service=64),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(CALENDAR_CFGS))
+def test_calendar_matches_ring(cell):
+    """At unit rate with no fault model the slot step takes departures
+    from a calendar written at admission; given unit ``service_rates``
+    (one unit per slot by the credit schedule) the same cell runs the job
+    ring's countdown.  Both must give the same results."""
+    cfg = CALENDAR_CFGS[cell]
+    ring = dataclasses.replace(
+        cfg, service_rates=(1.0,) * cfg.servers, rate_aware=False
+    )
+    assert slotted_sim._uses_calendar(cfg.static_part())
+    assert not slotted_sim._uses_calendar(ring.static_part())
+    a = simulate(jax.random.key(5), cfg)
+    b = simulate(jax.random.key(5), ring)
+    assert a.departures > 0
+    if cell == "drops":
+        assert a.dropped > 0
+    for f in dataclasses.fields(a):
+        assert np.array_equal(getattr(a, f.name), getattr(b, f.name)), f.name
+
+
 class TestGridSpans:
     """One call's host spans (``repro.spans``): its four phases and the
     counts that are their bases."""
@@ -201,15 +246,24 @@ class TestGridSpans:
         assert all(rec.children[n] > 0 for n in names)
         assert sum(rec.children.values()) <= rec.seconds
 
-    @pytest.mark.parametrize("count", ["runs", "bytes", "jobs"])
+    @pytest.mark.parametrize("count",
+                             ["runs", "bytes", "jobs", "calendar_runs"])
     def test_count_is_its_base(self, call, count):
         grid, rec, out_bytes = call
         want = {
             "runs": len(GRID_CFGS) * len(GRID_SEEDS),
             "bytes": out_bytes,
             "jobs": sum(len(r.jct) for cell in grid for r in cell),
+            # Unit rates, no fault model: every run takes the calendar.
+            "calendar_runs": len(GRID_CFGS) * len(GRID_SEEDS),
         }[count]
         assert rec.counts[count] == want
+
+    def test_ring_runs_count_no_calendar(self):
+        cfg = SimConfig(slots=500, load=0.9, service_rates=(1.0,) * 30)
+        simulate_grid([1, 2], cfg.static_part(), [cfg.scenario()])
+        rec = spans.last("simulate_grid")
+        assert (rec.counts["runs"], rec.counts["calendar_runs"]) == (2, 0)
 
 
 # ---------------------------------------------------------------------------
