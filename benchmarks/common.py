@@ -119,7 +119,8 @@ def percell_reference(
         results.append(
             [
                 slotted_sim._finalize(
-                    out_np[0][i], tuple(o[i] for o in out_np[1:])
+                    out_np[0][i], tuple(o[i] for o in out_np[1:]),
+                    slotted_sim._extra_outputs(static),
                 )
                 for i in range(len(seeds))
             ]

@@ -87,11 +87,15 @@ on the static kinds:
 
 * At unit rate with no fault model (no ``service_rates``, ``fault="none"``)
   every server works one unit per slot, so a job's departure slot follows
-  from Lindley's recursion when it is admitted.  The scan keeps a per-run
-  departure *calendar* (one bit per server and slot) that admission
+  from Lindley's recursion when it is admitted.  The scan keeps per run
+  one bit per server and slot of the departures to come, which admission
   writes and step 2 reads at the slot index, and emits each admitted
-  job's completion slot as its per-slot output.  No per-job state is
-  gathered inside the loop and nothing is scattered after it.
+  job's completion slot as its per-slot output.  A departure due within
+  the next ``NEAR_WINDOW`` slots is ORed into a circular *window* by a
+  dense one-hot select; only a later one goes into the full departure
+  *calendar*, by a scatter that runs when some run of the batch needs
+  it.  No per-job state is gathered inside the loop and nothing is
+  scattered after it.
 * Otherwise (the credit schedule of heterogeneous rates, crash or slow
   faults) the work per slot varies: job FIFOs are circular buffers of job
   ids, the head job counts down its remaining size, and the completion
@@ -116,7 +120,8 @@ device trace's operations map back to them through the executable's
 order) and, on the ring path, ``complete`` (the completion-slot scatter
 after the scan).  :func:`simulate_grid` also records host spans
 (:mod:`repro.spans`); its root span counts ``calendar_runs``, the runs
-that took the calendar path.
+that took the calendar path, and ``far_departures``, the departures
+written past the window.
 """
 from __future__ import annotations
 
@@ -136,6 +141,15 @@ from repro.core.care import routing as routing_lib
 from repro.core.care import workload as workload_lib
 
 CommKind = comm_lib.CommKind
+
+# Slots ahead that the departure window covers (capped by the scan length).
+# At the paper's loads a departure lies a few hundred slots past admission
+# at most (a geometric size of mean 30 passes 512 with probability ~3e-8),
+# so almost every departure is written to the window.
+NEAR_WINDOW = 512
+# The vmapped run axis of a grid program; the far departure write reduces
+# its predicate over it.
+_BATCH_AXIS = "batch"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -589,6 +603,9 @@ class SimResult:
     # Pull-policy counters (jiq / hsq; zero otherwise).
     token_misses: int = 0  # arrivals routed with an empty token pool
     token_sum: int = 0  # sum over active slots of end-of-slot pool size
+    # Departures written to the full calendar because they fell past the
+    # departure window (calendar path; zero otherwise).
+    far_departures: int = 0
 
 
 @dataclasses.dataclass
@@ -622,11 +639,16 @@ class _Carry:
     tokens: Optional[jnp.ndarray] = None  # (K,) i32 balancer token pool
     token_miss: Optional[jnp.ndarray] = None  # () i32 empty-pool routings
     token_sum: Optional[jnp.ndarray] = None  # () i32 summed pool occupancy
-    # Departure calendar (calendar path only): the departure slot of each
-    # server's last admitted job, and one bit per server of the servers
-    # departing in each slot, W = ceil(K / 32) words per slot.
+    # Departures (calendar path only): the departure slot of each server's
+    # last admitted job, and one bit per server of the servers departing
+    # in a slot, W = ceil(K / 32) words per slot: in the circular window
+    # of the next N = min(T, NEAR_WINDOW) slots, and, when the window is
+    # shorter than the scan, in the calendar of all T slots for the
+    # departures past it, with their count.
     last_dep: Optional[jnp.ndarray] = None  # (K,) i32
+    win: Optional[jnp.ndarray] = None  # (N * W,) u32
     cal: Optional[jnp.ndarray] = None  # (T * W,) u32
+    far_deps: Optional[jnp.ndarray] = None  # () i32
 
 
 jax.tree_util.register_dataclass(
@@ -695,7 +717,7 @@ def _prep(key: jax.Array, static: StaticConfig, scn: Scenario):
 
 def _sim_core(
     arrive, sizes, slot_keys, active, static: StaticConfig, scn: Scenario,
-    net_keys=None, fault_keys=None, classes=None,
+    net_keys=None, fault_keys=None, classes=None, axis_name=None,
 ):
     """One full slotted run as a lax.scan; traceable (also under vmap).
 
@@ -704,7 +726,9 @@ def _sim_core(
     the per-slot horizon mask: on inactive slots every carry field is
     frozen (no service, no emulation drain, no trigger evaluation), so a
     padded scan produces exactly the state a shorter scan would leave
-    behind.
+    behind.  ``axis_name`` names the ``vmap`` axis the run is batched
+    over, if any: the calendar's far write then runs when any run of the
+    batch needs it, in place of in every step.
     """
     k = static.servers
     b = static.buffer_cap
@@ -784,9 +808,12 @@ def _sim_core(
         drain_slots = None
     t = arrive.shape[0]
     calendar = _uses_calendar(static)
-    # The calendar keeps one word of departure bits per slot and per 32
-    # servers, flat so that a run's calendar is one long lane axis.
+    # Departure bits are kept as one word per slot and per 32 servers, flat
+    # so that a run's window and calendar are each one long lane axis.
     words = -(-k // 32)
+    near = min(t, NEAR_WINDOW)
+    # Whether the window wraps round, and a calendar holds what lies past it.
+    wraps = near < t
 
     def slot(c: _Carry, xs):
         arr, size, jid, skey, act = xs[:5]
@@ -899,14 +926,48 @@ def _sim_core(
                 d = (jnp.maximum(jid, last_sel + 1)
                      + jnp.minimum(size, t + 1) - 1)
                 last_dep = jnp.where(sel, jnp.minimum(d, t), c.last_dep)
-                # One bit per departure, added: two servers of one word may
-                # depart in the same slot.  A departure past the scan is
-                # written nowhere (the index is out of range).
-                word_at = jnp.where(admit & (d < t),
-                                    d * words + server // 32, t * words)
+                # One bit per departure, ORed in: two servers of one word
+                # may depart in the same slot.  A departure past the scan
+                # is written nowhere.
+                due = admit & (d < t)
+                is_near = due & (d - jid < near)
                 bit = jnp.left_shift(jnp.uint32(1),
                                      server.astype(jnp.uint32) % 32)
-                cal = c.cal.at[word_at].add(bit, mode="drop")
+                # The window, a dense one-hot select (under vmap a
+                # dynamic_update_slice would become a scatter).  Slot
+                # jid - 1 was read in the step before: clear it first, as
+                # a departure now may fall in its place.
+                lane = jnp.arange(near * words, dtype=jnp.int32)
+                win = c.win
+                if wraps:
+                    win = jnp.where(lane // words == (jid - 1) % near, 0, win)
+                near_at = jnp.where(
+                    is_near, (d % near) * words + server // 32, -1
+                )
+                win = jnp.where(lane == near_at, win | bit, win)
+                if wraps:
+                    # The rare departure past the window goes to the full
+                    # calendar, by a scatter that runs only in the steps in
+                    # which some run of the batch has one.
+                    is_far = due & ~is_near
+                    far_deps = c.far_deps + is_far.astype(jnp.int32)
+                    word_at = jnp.where(is_far, d * words + server // 32,
+                                        t * words)
+                    # Under vmap a batched predicate would make the cond
+                    # a select that scatters in every step: reduce it
+                    # over the batch first.
+                    any_far = is_far
+                    if axis_name is not None:
+                        any_far = jax.lax.pmax(
+                            is_far.astype(jnp.int32), axis_name) > 0
+                    cal = jax.lax.cond(
+                        any_far,
+                        lambda cal: cal.at[word_at].add(bit, mode="drop"),
+                        lambda cal: cal,
+                        c.cal,
+                    )
+                else:
+                    cal = far_deps = None
                 comp = jnp.where(admit & (d < scn.horizon) & (d < t), d, -1)
                 buf_jid = head_rem = None
                 q_true = c.q_true + sel.astype(jnp.int32)
@@ -920,7 +981,7 @@ def _sim_core(
                 )
                 q_true = c.q_true + sel.astype(jnp.int32)
                 head_rem = jnp.where(sel & (c.q_true == 0), size, c.head_rem)
-                last_dep = cal = None
+                last_dep = win = cal = far_deps = None
             emu = approx_lib.emu_arrival_masked(c.emu, sel, acfg)
             arrs = c.arrs + admit.astype(jnp.int32)
             per_srv = c.per_srv + sel.astype(jnp.int32)
@@ -931,7 +992,13 @@ def _sim_core(
                 # The slot's word, read at the loop counter (one index for
                 # every run of a batch), spread to one bit per server.
                 # Past the horizon (act False) nothing departs.
-                word = jax.lax.dynamic_slice(cal, (jid * words,), (words,))
+                word = jax.lax.dynamic_slice(
+                    win, ((jid % near) * words,), (words,)
+                )
+                if wraps:
+                    word = word | jax.lax.dynamic_slice(
+                        cal, (jid * words,), (words,)
+                    )
                 bits = jnp.broadcast_to(word[:, None], (words, 32)).reshape(-1)
                 shift = jnp.arange(k, dtype=jnp.uint32) % 32
                 dep = act & ((bits[:k] >> shift) & 1 == 1)
@@ -1110,7 +1177,9 @@ def _sim_core(
                 token_miss=token_miss,
                 token_sum=token_sum,
                 last_dep=last_dep,
+                win=win,
                 cal=cal,
+                far_deps=far_deps,
             )
         return carry, out_t
 
@@ -1140,7 +1209,10 @@ def _sim_core(
         token_miss=jnp.zeros((), jnp.int32) if has_pull else None,
         token_sum=jnp.zeros((), jnp.int32) if has_pull else None,
         last_dep=jnp.full((k,), -1, jnp.int32) if calendar else None,
-        cal=jnp.zeros((t * words,), jnp.uint32) if calendar else None,
+        win=jnp.zeros((near * words,), jnp.uint32) if calendar else None,
+        cal=(jnp.zeros((t * words,), jnp.uint32)
+             if calendar and wraps else None),
+        far_deps=jnp.zeros((), jnp.int32) if calendar and wraps else None,
     )
     xs = (arrive, sizes, jnp.arange(t, dtype=jnp.int32), slot_keys, active)
     if has_cls:
@@ -1181,15 +1253,32 @@ def _sim_core(
         final.token_miss if has_pull else jnp.zeros((), jnp.int32),
         final.token_sum if has_pull else jnp.zeros((), jnp.int32),
     )
+    # Outputs past these 13 exist only where their kind is on, so every
+    # other program keeps its output arity (byte-identical); they are
+    # named, in order, by _extra_outputs.
+    if calendar:
+        out = out + (
+            final.far_deps if wraps else jnp.zeros((), jnp.int32),
+        )
     if has_ack:
-        # Appended only under transport="ack" so every fire_forget
-        # program keeps its historical output arity (byte-identical).
         out = out + (final.net.retrans,)
     return out
 
 
-def _run_one(key, scn: Scenario, static: StaticConfig):
-    """Workload draw + scan for one (key, scenario) pair; vmap-able."""
+def _extra_outputs(static: StaticConfig) -> Tuple[str, ...]:
+    """The :class:`SimResult` fields of the outputs that a run's program
+    appends after the 13 every program has, in order."""
+    if static.route_backend == "pallas":
+        return ()
+    names = ("far_departures",) if _uses_calendar(static) else ()
+    if static.network != "none" and static.transport == "ack":
+        names += ("retrans",)
+    return names
+
+
+def _run_one(key, scn: Scenario, static: StaticConfig, axis_name=None):
+    """Workload draw + scan for one (key, scenario) pair; vmap-able
+    (``axis_name``: see :func:`_sim_core`)."""
     with jax.named_scope("draw"):
         prep = _prep(key, static, scn)
     arrive, sizes, slot_keys, act = prep[:4]
@@ -1200,6 +1289,7 @@ def _run_one(key, scn: Scenario, static: StaticConfig):
     return (arrive,) + _sim_core(
         arrive, sizes, slot_keys, act, static, scn,
         net_keys=net_keys, fault_keys=fault_keys, classes=classes,
+        axis_name=axis_name,
     )
 
 
@@ -1218,7 +1308,10 @@ def _grid_fn(static: StaticConfig, n_dev: int):
     for a different shard count.  ``n_dev == 1`` skips the mesh entirely
     (plain jitted vmap), which is also the path `shard=False` forces.
     """
-    batched = jax.vmap(lambda key, scn: _run_one(key, scn, static))
+    batched = jax.vmap(
+        lambda key, scn: _run_one(key, scn, static, _BATCH_AXIS),
+        axis_name=_BATCH_AXIS,
+    )
     fn = jax.jit(shard_runs(batched, n_dev, 2))
     _GRID_PROGRAMS.append(fn)
     return fn
@@ -1545,12 +1638,18 @@ def _check_control_plane(static: StaticConfig, scn: Scenario) -> None:
             raise ValueError("slow_factor must be in (0, 1]")
 
 
-def _finalize(arrive_np: np.ndarray, out) -> SimResult:
-    """Convert one run's device outputs into a host-side SimResult."""
+def _finalize(arrive_np: np.ndarray, out, extras: Sequence[str]) -> SimResult:
+    """Convert one run's device outputs into a host-side SimResult.
+
+    ``extras`` names the outputs past the first 13
+    (:func:`_extra_outputs` of the run's static configuration).
+    """
     out = tuple(out)
-    # transport="ack" programs append a retransmit counter; fire_forget
-    # keeps the historical 13-output tuple.
-    retrans = np.asarray(out[13]) if len(out) > 13 else np.int32(0)
+    if len(out) != 13 + len(extras):
+        raise ValueError(
+            f"{len(out)} outputs for the 13 and {list(extras)}"
+        )
+    extra = {name: int(v) for name, v in zip(extras, out[13:])}
     (comp_slot, msgs, deps, arrs, max_aq, max_q, per_srv, final_q, dropped,
      gap_sup, net_drops, token_miss, token_sum) = (
         np.asarray(o) for o in out[:13]
@@ -1577,9 +1676,9 @@ def _finalize(arrive_np: np.ndarray, out) -> SimResult:
         queue_gap_sup=int(gap_sup),
         dropped=int(dropped),
         net_drops=int(net_drops),
-        retrans=int(retrans),
         token_misses=int(token_miss),
         token_sum=int(token_sum),
+        **extra,
     )
 
 
@@ -1598,10 +1697,11 @@ def simulate(key: jax.Array, cfg: SimConfig) -> SimResult:
             key[None], jax.tree.map(lambda a: a[None], scn)
         )
         return _finalize(
-            np.asarray(out[0][0]), tuple(o[0] for o in out[1:])
+            np.asarray(out[0][0]), tuple(o[0] for o in out[1:]),
+            _extra_outputs(static),
         )
     out = _simulate_jit(key, scn, static)
-    return _finalize(np.asarray(out[0]), out[1:])
+    return _finalize(np.asarray(out[0]), out[1:], _extra_outputs(static))
 
 
 def grid_program(
@@ -1700,16 +1800,20 @@ def simulate_grid(
                         bytes=sum(o.nbytes for o in out)):
             out_np = [np.asarray(o)[: c * s] for o in out]
         arrive, rest = out_np[0], out_np[1:]
+        extras = _extra_outputs(static_cfg)
         with spans.span("simulate_grid.finalize") as fin:
             results = [
                 [
                     _finalize(arrive[i * s + j],
-                              tuple(o[i * s + j] for o in rest))
+                              tuple(o[i * s + j] for o in rest), extras)
                     for j in range(s)
                 ]
                 for i in range(c)
             ]
             fin.count(jobs=sum(r.jct.size for row in results for r in row))
+        root.count(far_departures=sum(
+            r.far_departures for row in results for r in row
+        ))
     return results
 
 

@@ -224,11 +224,28 @@ def test_paper_grid_phases_own_the_scan_body(paper_grid):
     # ring or the sizes (a gather of the calendar's word would hold one
     # index per run), and completion slots are the loop's own output, so
     # no scatter or sort follows it.
-    runs = paper_grid[1]
+    runs, slots = paper_grid[1:]
     for opcode, _, line in _with_fusions(comps, comps[body]):
         if opcode == "gather":
             assert re.search(rf"= u32\[{runs}(,1)?\]", line), line
     assert not any(opcode in ("scatter", "sort") for opcode, _, _ in after)
+    # Departures are written to the window by a dense select; the
+    # calendar's scatter, for the rare departure past the window, runs
+    # only in a conditional's branch, and nothing copies the calendar.
+    assert not any(opcode == "scatter"
+                   for opcode, _, _ in _with_fusions(comps, comps[body]))
+    branches = [
+        callee
+        for _, _, line in _with_fusions(comps, comps[body])
+        for group in re.findall(r"branch_computations=\{([^}]*)\}", line)
+        for callee in re.findall(r"%([\w.\-]+)", group)
+    ]
+    in_branches = [inst for callee in branches
+                   for inst in _with_fusions(comps, comps[callee])]
+    assert [op for op, _, _ in in_branches].count("scatter") == 1
+    calendar = rf"u32\[{runs},{slots}\]"
+    for opcode, _, line in list(_with_fusions(comps, comps[body])) + in_branches:
+        assert not (opcode == "copy" and re.search(rf"= {calendar}", line)), line
 
 
 def test_serving_grid_program_fits_one_chip(one_chip):

@@ -197,7 +197,16 @@ CALENDAR_CFGS = {
     "jiq": SimConfig(slots=1500, load=0.9, policy="jiq", comm="jiq"),
     "k40": SimConfig(slots=1500, load=0.95, servers=40, mean_service=40),
     "k64": SimConfig(slots=1500, load=0.95, servers=64, mean_service=64),
+    # Departures past the window go to the full calendar: every job of
+    # size 700, and the backlog of two servers near saturation.
+    "far_deterministic": SimConfig(slots=3000, load=0.9,
+                                   service="deterministic", mean_service=700),
+    "far_backlog": SimConfig(slots=4000, load=0.99, servers=2,
+                             mean_service=200),
+    # A scan no longer than the window: the window is the whole calendar.
+    "short": SimConfig(slots=slotted_sim.NEAR_WINDOW, load=0.95),
 }
+FAR_CELLS = ("far_deterministic", "far_backlog")
 
 
 @pytest.mark.parametrize("cell", sorted(CALENDAR_CFGS))
@@ -217,8 +226,39 @@ def test_calendar_matches_ring(cell):
     assert a.departures > 0
     if cell == "drops":
         assert a.dropped > 0
+    # How the calendar wrote its departures is not a result of the run.
     for f in dataclasses.fields(a):
-        assert np.array_equal(getattr(a, f.name), getattr(b, f.name)), f.name
+        if f.name != "far_departures":
+            assert np.array_equal(getattr(a, f.name),
+                                  getattr(b, f.name)), f.name
+    assert b.far_departures == 0
+    if cell in FAR_CELLS:
+        assert a.far_departures > 0
+    if cfg.static_part().slots <= slotted_sim.NEAR_WINDOW:
+        assert a.far_departures == 0
+
+
+def test_grid_with_some_far_runs_matches_per_run():
+    """In a batch where some runs write past the window and others do
+    not, the far write runs for the whole batch: a run with nothing to
+    write must come out as it does alone."""
+    cfgs = [
+        SimConfig(slots=3000, load=0.9, service="deterministic",
+                  mean_service=m)
+        for m in (700, 20)
+    ]
+    static = cfgs[0].static_part()
+    assert cfgs[1].static_part() == static
+    grid = simulate_grid([1, 2], static, [c.scenario() for c in cfgs])
+    far = [[r.far_departures for r in row] for row in grid]
+    assert all(n > 0 for n in far[0]) and all(n == 0 for n in far[1])
+    assert spans.last("simulate_grid").counts["far_departures"] == sum(far[0])
+    for row, cfg in zip(grid, cfgs):
+        for res, seed in zip(row, (1, 2)):
+            want = simulate(jax.random.key(seed), cfg)
+            for f in dataclasses.fields(res):
+                assert np.array_equal(getattr(res, f.name),
+                                      getattr(want, f.name)), f.name
 
 
 class TestGridSpans:
@@ -246,8 +286,9 @@ class TestGridSpans:
         assert all(rec.children[n] > 0 for n in names)
         assert sum(rec.children.values()) <= rec.seconds
 
-    @pytest.mark.parametrize("count",
-                             ["runs", "bytes", "jobs", "calendar_runs"])
+    @pytest.mark.parametrize(
+        "count", ["runs", "bytes", "jobs", "calendar_runs", "far_departures"]
+    )
     def test_count_is_its_base(self, call, count):
         grid, rec, out_bytes = call
         want = {
@@ -256,6 +297,8 @@ class TestGridSpans:
             "jobs": sum(len(r.jct) for cell in grid for r in cell),
             # Unit rates, no fault model: every run takes the calendar.
             "calendar_runs": len(GRID_CFGS) * len(GRID_SEEDS),
+            "far_departures": sum(r.far_departures
+                                  for cell in grid for r in cell),
         }[count]
         assert rec.counts[count] == want
 
@@ -263,7 +306,8 @@ class TestGridSpans:
         cfg = SimConfig(slots=500, load=0.9, service_rates=(1.0,) * 30)
         simulate_grid([1, 2], cfg.static_part(), [cfg.scenario()])
         rec = spans.last("simulate_grid")
-        assert (rec.counts["runs"], rec.counts["calendar_runs"]) == (2, 0)
+        assert (rec.counts["runs"], rec.counts["calendar_runs"],
+                rec.counts["far_departures"]) == (2, 0, 0)
 
 
 # ---------------------------------------------------------------------------
@@ -428,7 +472,9 @@ import jax
 assert jax.local_device_count() == {n_dev}, jax.local_device_count()
 """
 
-# 3 cells x 1 seed = 3 runs: ragged over n_dev devices, exercising padding.
+# 3 cells x 1 seed = 3 runs: ragged over n_dev devices, exercising padding
+# (the slotted tier adds a fourth, whose jobs of mean size 300 depart past
+# the departure window: one shard writes the far calendar, others not).
 _SUBPROCESS_SCRIPTS = {
     "slotted": """
 from repro.core import SimConfig, simulate_grid
@@ -437,10 +483,13 @@ cfgs = [
     SimConfig(slots=2000, load=0.95, x=3),
     SimConfig(slots=2000, load=0.8, x=2),
     SimConfig(slots=2000, load=0.5, x=4),
+    SimConfig(slots=2000, load=0.95, x=3, mean_service=300),
 ]
 grid = simulate_grid([11], cfgs[0].static_part(), [c.scenario() for c in cfgs])
+assert grid[3][0].far_departures > 0
 print(json.dumps([
     dict(messages=r[0].messages, max_aq=r[0].max_aq,
+         far_departures=r[0].far_departures,
          jct=np.asarray(r[0].jct).tolist())
     for r in grid
 ]))
