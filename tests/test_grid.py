@@ -177,6 +177,8 @@ class TestSimulateGrid:
 
 
 ALL_K30 = tuple([True] * 30)
+# A job size that departs past the departure window even on an idle server.
+FAR_SIZE = slotted_sim.NEAR_WINDOW + 200
 CALENDAR_CFGS = {
     "geometric": SimConfig(slots=1500, load=0.95, x=3),
     "deterministic": SimConfig(slots=1500, load=0.9, service="deterministic",
@@ -197,10 +199,12 @@ CALENDAR_CFGS = {
     "jiq": SimConfig(slots=1500, load=0.9, policy="jiq", comm="jiq"),
     "k40": SimConfig(slots=1500, load=0.95, servers=40, mean_service=40),
     "k64": SimConfig(slots=1500, load=0.95, servers=64, mean_service=64),
-    # Departures past the window go to the full calendar: every job of
-    # size 700, and the backlog of two servers near saturation.
+    # Departures past the window go to the full calendar: every job of a
+    # size longer than the window, and the backlog of two servers near
+    # saturation.
     "far_deterministic": SimConfig(slots=3000, load=0.9,
-                                   service="deterministic", mean_service=700),
+                                   service="deterministic",
+                                   mean_service=FAR_SIZE),
     "far_backlog": SimConfig(slots=4000, load=0.99, servers=2,
                              mean_service=200),
     # A scan no longer than the window: the window is the whole calendar.
@@ -245,7 +249,7 @@ def test_grid_with_some_far_runs_matches_per_run():
     cfgs = [
         SimConfig(slots=3000, load=0.9, service="deterministic",
                   mean_service=m)
-        for m in (700, 20)
+        for m in (FAR_SIZE, 20)
     ]
     static = cfgs[0].static_part()
     assert cfgs[1].static_part() == static
