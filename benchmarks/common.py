@@ -115,15 +115,9 @@ def percell_reference(
             lambda key, s: slotted_sim._run_one(key, s, static)
         )
         out = jax.jit(slotted_sim.shard_runs(batched, n_dev, 2))(keys, scn)
-        out_np = [np.asarray(o) for o in out]
+        out_np = jax.device_get(out)
         results.append(
-            [
-                slotted_sim._finalize(
-                    out_np[0][i], tuple(o[i] for o in out_np[1:]),
-                    slotted_sim._extra_outputs(static),
-                )
-                for i in range(len(seeds))
-            ]
+            [slotted_sim._finalize(out_np.run(i)) for i in range(len(seeds))]
         )
     return results
 

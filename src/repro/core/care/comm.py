@@ -56,7 +56,7 @@ backends be bit-identical.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Literal, Tuple
+from typing import Any, Literal, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -307,6 +307,29 @@ class NetworkConfig:
     backoff_base: Any = 1.0  # timeout multiplier per retransmit (>= 1)
     max_retries: Any = 0  # retransmits before the update is abandoned
     ka_period: Any = 0  # server keepalive period in slots (0 = none)
+
+
+def network_config(
+    network: str, transport: str, scn
+) -> Optional[NetworkConfig]:
+    """The :class:`NetworkConfig` of a traced program: its static kinds,
+    and its numeric operands from ``scn``, a scenario with the fields
+    ``net_delay``, ``net_jitter``, ``net_drop`` and, under
+    ``transport="ack"``, ``ack_timeout``, ``backoff_base``, ``max_retries``
+    and ``ka_period``.  ``None`` without a network model."""
+    if network == "none":
+        return None
+    if transport == "ack":
+        return NetworkConfig(
+            kind=network, delay=scn.net_delay, jitter=scn.net_jitter,
+            drop=scn.net_drop, transport="ack", ack_timeout=scn.ack_timeout,
+            backoff_base=scn.backoff_base, max_retries=scn.max_retries,
+            ka_period=scn.ka_period,
+        )
+    return NetworkConfig(
+        kind=network, delay=scn.net_delay, jitter=scn.net_jitter,
+        drop=scn.net_drop,
+    )
 
 
 @jax.tree_util.register_dataclass

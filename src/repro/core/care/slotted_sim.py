@@ -82,28 +82,11 @@ program is bit-identical to the historical compile-per-cell program
 
 The whole simulation is a single ``jax.lax.scan``; all per-server state is
 vectorised, so the simulator jit-compiles **once per StaticConfig** and
-runs at native speed on CPU/TPU.  How a server's FIFO is carried depends
-on the static kinds:
-
-* At unit rate with no fault model (no ``service_rates``, ``fault="none"``)
-  every server works one unit per slot, so a job's departure slot follows
-  from Lindley's recursion when it is admitted.  The scan keeps per run
-  one bit per server and slot of the departures to come, which admission
-  writes and step 2 reads at the slot index, and emits each admitted
-  job's completion slot as its per-slot output.  A departure due within
-  the next ``NEAR_WINDOW`` slots is ORed into a circular *window* by a
-  dense one-hot select; only a later one goes into the full departure
-  *calendar*, by a scatter that runs when some run of the batch needs
-  it.  No per-job state is gathered inside the loop and nothing is
-  scattered after it.
-* Otherwise (the credit schedule of heterogeneous rates, crash or slow
-  faults) the work per slot varies: job FIFOs are circular buffers of job
-  ids, the head job counts down its remaining size, and the completion
-  slot of each departed id is scattered after the scan.
-
-``buffer_cap`` bounds admission on both paths (a full FIFO drops the
-arrival); only the ring path also uses it as an array shape.  Batching
-entry points:
+runs at native speed on CPU/TPU.  How a server's FIFO is carried (a
+departure calendar, or a ring of job ids) depends on the static kinds
+and lives in :mod:`repro.core.care.fifo`.  ``buffer_cap`` bounds
+admission on both carriages (a full FIFO drops the arrival); only the
+ring also uses it as an array shape.  Batching entry points:
 
 * :func:`simulate` -- one key, one cell.
 * :func:`simulate_batch` -- vmap over a batch of PRNG keys for one cell.
@@ -118,10 +101,10 @@ device trace's operations map back to them through the executable's
 ``op_name`` metadata: ``draw`` (the workload draw), ``faults``, ``route``,
 ``service``, ``drain``, ``trigger`` and ``metrics`` (the slot step, in
 order) and, on the ring path, ``complete`` (the completion-slot scatter
-after the scan).  :func:`simulate_grid` also records host spans
-(:mod:`repro.spans`); its root span counts ``calendar_runs``, the runs
-that took the calendar path, and ``far_departures``, the departures
-written past the window.
+after the scan).  A run's outputs are one :class:`_RunOut`.
+:func:`simulate_grid` also records host spans (:mod:`repro.spans`); its
+root span counts ``calendar_runs``, the runs that took the calendar, and
+``far_departures``, the departures written past its window.
 """
 from __future__ import annotations
 
@@ -137,23 +120,11 @@ from jax.sharding import Mesh, PartitionSpec as P
 from repro import spans
 from repro.core.care import approx as approx_lib
 from repro.core.care import comm as comm_lib
+from repro.core.care import fifo as fifo_lib
 from repro.core.care import routing as routing_lib
 from repro.core.care import workload as workload_lib
 
 CommKind = comm_lib.CommKind
-
-# Slots ahead that the departure window covers (capped by the scan length).
-# At the paper's loads a departure lies a few hundred slots past admission
-# at most (a geometric size of mean 30 passes 1024 with probability
-# ~1e-15), so almost every departure is written to the window.  On a TPU
-# v5e the paper grid's step is slightly faster with 1024 slots than with
-# 512 (whose far writes run in a few hundred steps per call) and twice as
-# slow with 256.
-NEAR_WINDOW = 1024
-# The vmapped run axis of a grid program; the far departure write reduces
-# its predicate over it.
-_BATCH_AXIS = "batch"
-
 
 @dataclasses.dataclass(frozen=True)
 class StaticConfig:
@@ -611,14 +582,40 @@ class SimResult:
     far_departures: int = 0
 
 
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass(frozen=True)
+class _RunOut:
+    """One run's outputs, on the device or fetched (with a leading run
+    axis when batched); :func:`_finalize` makes its :class:`SimResult`."""
+
+    arrive: jnp.ndarray  # (T,) bool arrival flags (the job ids)
+    comp_slot: jnp.ndarray  # (T,) i32 completion slot per job id, or -1
+    msgs: jnp.ndarray
+    deps: jnp.ndarray
+    arrs: jnp.ndarray
+    max_aq: jnp.ndarray
+    max_q: jnp.ndarray
+    per_srv: jnp.ndarray  # (K,)
+    final_q: jnp.ndarray  # (K,)
+    dropped: jnp.ndarray
+    gap_sup: jnp.ndarray
+    net_drops: jnp.ndarray
+    token_misses: jnp.ndarray
+    token_sum: jnp.ndarray
+    # None unless the run carried a calendar (and the dense backend ran).
+    far_departures: Optional[jnp.ndarray] = None
+    retrans: Optional[jnp.ndarray] = None  # None unless transport="ack"
+
+    def run(self, i: int) -> "_RunOut":
+        """Run ``i`` of a batch of fetched outputs."""
+        return _RunOut(**{name: None if v is None else v[i]
+                          for name, v in vars(self).items()})
+
+
+@jax.tree_util.register_dataclass
 @dataclasses.dataclass
 class _Carry:
     q_true: jnp.ndarray  # (K,) true queue lengths
-    # The job ring and the head job's countdown; None on the calendar path
-    # (see _uses_calendar), which carries last_dep and cal instead.
-    head_rem: Optional[jnp.ndarray]  # (K,) remaining slots of in-service job
-    buf_jid: Optional[jnp.ndarray]  # (K, B) FIFO ring of job ids (arrival slots)
-    head_ptr: Optional[jnp.ndarray]  # (K,) FIFO head index
     emu: approx_lib.EmuState
     comm: comm_lib.CommState  # shared trigger bookkeeping + message total
     rr_ptr: jnp.ndarray  # () round-robin pointer
@@ -642,41 +639,16 @@ class _Carry:
     tokens: Optional[jnp.ndarray] = None  # (K,) i32 balancer token pool
     token_miss: Optional[jnp.ndarray] = None  # () i32 empty-pool routings
     token_sum: Optional[jnp.ndarray] = None  # () i32 summed pool occupancy
-    # Departures (calendar path only): the departure slot of each server's
-    # last admitted job, and one bit per server of the servers departing
-    # in a slot, W = ceil(K / 32) words per slot: in the circular window
-    # of the next N = min(T, NEAR_WINDOW) slots, and, when the window is
-    # shorter than the scan, in the calendar of all T slots for the
-    # departures past it, with their count.
-    last_dep: Optional[jnp.ndarray] = None  # (K,) i32
-    win: Optional[jnp.ndarray] = None  # (N * W,) u32
-    cal: Optional[jnp.ndarray] = None  # (T * W,) u32
-    far_deps: Optional[jnp.ndarray] = None  # () i32
-
-
-jax.tree_util.register_dataclass(
-    _Carry, data_fields=[f.name for f in dataclasses.fields(_Carry)], meta_fields=[]
-)
-
-
-def _uses_calendar(static: StaticConfig) -> bool:
-    """Whether the slot step finds departures in a calendar.
-
-    A server that works one unit in every slot, as at unit rate with no
-    fault model, serves a FIFO by Lindley's recursion: a job of size ``S``
-    admitted in slot ``t`` departs in slot ``max(t, D + 1) + S - 1``, where
-    ``D`` is the departure slot of the job admitted before it.  Admission
-    then knows the departure, so the scan needs no job ring.  Otherwise
-    the work per slot varies (the credit schedule of
-    ``workload.service_units``, crash and slow faults) and the ring's
-    countdown stays.
-    """
-    return not static.use_rates and static.fault == "none"
+    # The servers' FIFOs (fifo.carriage); last, so that the calendar's
+    # leaves close the loop carry.
+    fifo: "fifo_lib.Calendar | fifo_lib.JobRing" = dataclasses.field(
+        kw_only=True)
 
 
 def _prep(key: jax.Array, static: StaticConfig, scn: Scenario):
-    """Draw the replayable workload: (arrive, sizes, slot_keys, active)
-    plus per-slot network / fault key streams when those kinds are on.
+    """Draw the replayable workload: (arrive, sizes, slot_keys, active,
+    classes, net_keys, fault_keys), the last three None where their kind
+    is off.
 
     Fully traceable in the scenario operands (the arrival and service
     *kinds* alone are static), so a grid of cells shares one compiled
@@ -701,28 +673,24 @@ def _prep(key: jax.Array, static: StaticConfig, scn: Scenario):
     arrive = arrive & active
     sizes = workload_lib.service_sizes(k_size, t, scn.service)
     slot_keys = jax.random.split(k_scan, t)
-    out = (arrive, sizes, slot_keys, active)
     # Class / control-plane randomness comes from fold_in-derived side
     # streams so the three historical children of `key` -- and therefore
     # the whole single-class "none"-kind sample path -- stay byte-stable.
+    classes = net_keys = fault_keys = None
     if static.classes > 1:
-        out += (
-            workload_lib.arrival_classes(
-                jax.random.fold_in(key, 13), t, scn.class_mix
-            ),
+        classes = workload_lib.arrival_classes(
+            jax.random.fold_in(key, 13), t, scn.class_mix
         )
     if static.network != "none":
-        out += (jax.random.split(jax.random.fold_in(key, 7), t),)
+        net_keys = jax.random.split(jax.random.fold_in(key, 7), t)
     if static.fault != "none":
-        out += (jax.random.split(jax.random.fold_in(key, 11), t),)
-    return out
+        fault_keys = jax.random.split(jax.random.fold_in(key, 11), t)
+    return arrive, sizes, slot_keys, active, classes, net_keys, fault_keys
 
 
-def _sim_core(
-    arrive, sizes, slot_keys, active, static: StaticConfig, scn: Scenario,
-    net_keys=None, fault_keys=None, classes=None, axis_name=None,
-):
-    """One full slotted run as a lax.scan; traceable (also under vmap).
+def _sim_core(draws, static: StaticConfig, scn: Scenario, axis_name=None):
+    """One full slotted run of :func:`_prep`'s ``draws`` as a lax.scan;
+    traceable (also under vmap).
 
     ``static`` selects code paths (Python ``if`` on kinds); every numeric
     scenario knob enters as a traced operand of ``scn``.  ``active`` is
@@ -730,9 +698,10 @@ def _sim_core(
     frozen (no service, no emulation drain, no trigger evaluation), so a
     padded scan produces exactly the state a shorter scan would leave
     behind.  ``axis_name`` names the ``vmap`` axis the run is batched
-    over, if any: the calendar's far write then runs when any run of the
-    batch needs it, in place of in every step.
+    over, if any (see ``fifo.Calendar.admit``).  Returns the run's
+    :class:`_RunOut`.
     """
+    arrive, sizes, slot_keys, active, classes, net_keys, fault_keys = draws
     k = static.servers
     b = static.buffer_cap
     if scn.service.kind != static.service:
@@ -767,27 +736,7 @@ def _sim_core(
             "per-departure message accounting (Prop 6.1) assumes instant "
             "delivery -- use comm='dt' with x=1 under network='net'"
         )
-    if has_ack:
-        ncfg = comm_lib.NetworkConfig(
-            kind=static.network,
-            delay=scn.net_delay,
-            jitter=scn.net_jitter,
-            drop=scn.net_drop,
-            transport="ack",
-            ack_timeout=scn.ack_timeout,
-            backoff_base=scn.backoff_base,
-            max_retries=scn.max_retries,
-            ka_period=scn.ka_period,
-        )
-    elif has_net:
-        ncfg = comm_lib.NetworkConfig(
-            kind=static.network,
-            delay=scn.net_delay,
-            jitter=scn.net_jitter,
-            drop=scn.net_drop,
-        )
-    else:
-        ncfg = None
+    ncfg = comm_lib.network_config(static.network, static.transport, scn)
     # Under a modeled network the query policies route on *stale* true
     # state: the 2d SQ(d) probes (and JSQ's state feed) suffer the same
     # delivery delay as push messages, read from a ring of end-of-slot
@@ -810,25 +759,9 @@ def _sim_core(
         rates = None
         drain_slots = None
     t = arrive.shape[0]
-    calendar = _uses_calendar(static)
-    # Departure bits are kept as one word per slot and per 32 servers, flat
-    # so that a run's window and calendar are each one long lane axis.
-    words = -(-k // 32)
-    near = min(t, NEAR_WINDOW)
-    # Whether the window wraps round, and a calendar holds what lies past it.
-    wraps = near < t
 
     def slot(c: _Carry, xs):
-        arr, size, jid, skey, act = xs[:5]
-        rest = xs[5:]
-        ri = 0
-        if has_cls:
-            cls_t = rest[ri]
-            ri += 1
-        else:
-            cls_t = None
-        nkey = rest[ri] if has_net else None
-        fkey = rest[-1] if has_fault else None
+        arr, size, jid, skey, act, cls_t, nkey, fkey = xs
 
         # --- 0. fault transitions -------------------------------------
         with jax.named_scope("faults"):
@@ -920,129 +853,33 @@ def _sim_core(
             admit = arr & (q_sel < b)
             dropped = c.dropped + (arr & ~admit).astype(jnp.int32)
             sel = onehot & admit
-            if calendar:
-                # Lindley's recursion (_uses_calendar): the job starts when
-                # it arrives or after the job before it departs.  A size
-                # past the scan cannot depart in it; capping it there keeps
-                # the sum in int32.
-                last_sel = jnp.sum(jnp.where(onehot, c.last_dep, 0))
-                d = (jnp.maximum(jid, last_sel + 1)
-                     + jnp.minimum(size, t + 1) - 1)
-                last_dep = jnp.where(sel, jnp.minimum(d, t), c.last_dep)
-                # One bit per departure, ORed in: two servers of one word
-                # may depart in the same slot.  A departure past the scan
-                # is written nowhere.
-                due = admit & (d < t)
-                is_near = due & (d - jid < near)
-                bit = jnp.left_shift(jnp.uint32(1),
-                                     server.astype(jnp.uint32) % 32)
-                # The window, a dense one-hot select (under vmap a
-                # dynamic_update_slice would become a scatter).  Slot
-                # jid - 1 was read in the step before: clear it first, as
-                # a departure now may fall in its place.
-                lane = jnp.arange(near * words, dtype=jnp.int32)
-                win = c.win
-                if wraps:
-                    win = jnp.where(lane // words == (jid - 1) % near, 0, win)
-                near_at = jnp.where(
-                    is_near, (d % near) * words + server // 32, -1
-                )
-                win = jnp.where(lane == near_at, win | bit, win)
-                if wraps:
-                    # The rare departure past the window goes to the full
-                    # calendar, by a scatter that runs only in the steps in
-                    # which some run of the batch has one.
-                    is_far = due & ~is_near
-                    far_deps = c.far_deps + is_far.astype(jnp.int32)
-                    word_at = jnp.where(is_far, d * words + server // 32,
-                                        t * words)
-                    # Under vmap a batched predicate would make the cond
-                    # a select that scatters in every step: reduce it
-                    # over the batch first.
-                    any_far = is_far
-                    if axis_name is not None:
-                        any_far = jax.lax.pmax(
-                            is_far.astype(jnp.int32), axis_name) > 0
-                    cal = jax.lax.cond(
-                        any_far,
-                        lambda cal: cal.at[word_at].add(bit, mode="drop"),
-                        lambda cal: cal,
-                        c.cal,
-                    )
-                else:
-                    cal = far_deps = None
-                comp = jnp.where(admit & (d < scn.horizon) & (d < t), d, -1)
-                buf_jid = head_rem = None
-                q_true = c.q_true + sel.astype(jnp.int32)
-            else:
-                head_sel = jnp.sum(jnp.where(onehot, c.head_ptr, 0))
-                tail = (head_sel + q_sel) % b
-                # Masked one-element scatter (the ring itself still needs
-                # indexing).
-                buf_jid = c.buf_jid.at[server, tail].set(
-                    jnp.where(admit, jid, c.buf_jid[server, tail])
-                )
-                q_true = c.q_true + sel.astype(jnp.int32)
-                head_rem = jnp.where(sel & (c.q_true == 0), size, c.head_rem)
-                last_dep = win = cal = far_deps = None
+            fifo, adm_out = c.fifo.admit(
+                jid, server, onehot, admit, sel, c.q_true, q_sel, size,
+                scn.horizon, axis_name,
+            )
+            q_true = c.q_true + sel.astype(jnp.int32)
             emu = approx_lib.emu_arrival_masked(c.emu, sel, acfg)
             arrs = c.arrs + admit.astype(jnp.int32)
             per_srv = c.per_srv + sel.astype(jnp.int32)
 
         # --- 2. service ------------------------------------------------
         with jax.named_scope("service"):
-            if calendar:
-                # The slot's word, read at the loop counter (one index for
-                # every run of a batch), spread to one bit per server.
-                # Past the horizon (act False) nothing departs.
-                word = jax.lax.dynamic_slice(
-                    win, ((jid % near) * words,), (words,)
+            # The units each server works this slot: the drain emulates
+            # the nominal ones, a fault model changes what the FIFO gets.
+            units = (workload_lib.service_units(jid, rates)
+                     if rates is not None else None)
+            if has_fault:
+                eff_units = workload_lib.faulted_service_units(
+                    jid, faulted,
+                    jnp.ones((k,), jnp.int32) if units is None else units,
+                    static.fault, scn.slow_factor, rates=rates,
                 )
-                if wraps:
-                    word = word | jax.lax.dynamic_slice(
-                        cal, (jid * words,), (words,)
-                    )
-                bits = jnp.broadcast_to(word[:, None], (words, 32)).reshape(-1)
-                shift = jnp.arange(k, dtype=jnp.uint32) % 32
-                dep = act & ((bits[:k] >> shift) & 1 == 1)
-                q_true = jnp.where(dep, q_true - 1, q_true)
-                deps = c.deps + jnp.sum(dep, dtype=jnp.int32)
-                units = head_ptr = None
-                out_t = comp
             else:
-                # Past the cell's horizon (act False) nothing serves: the mask
-                # freezes head_rem / q_true / deps exactly where the horizon left
-                # them.  `act & True` is the identity, so unpadded runs are
-                # bit-identical to the historical unmasked program.
-                busy = (q_true > 0) & act
-                if rates is None:  # then a fault model is on
-                    units = None
-                    eff_units = workload_lib.faulted_service_units(
-                        jid, faulted, jnp.ones((k,), jnp.int32),
-                        static.fault, scn.slow_factor,
-                    )
-                else:
-                    units = workload_lib.service_units(jid, rates)
-                    if has_fault:
-                        eff_units = workload_lib.faulted_service_units(
-                            jid, faulted, units, static.fault, scn.slow_factor,
-                            rates=rates,
-                        )
-                    else:
-                        eff_units = units
-                head_rem = jnp.where(busy, head_rem - eff_units, head_rem)
-                dep = busy & (head_rem <= 0)
-                departed_jid = jnp.where(
-                    dep, buf_jid[jnp.arange(k), c.head_ptr % b], -1
-                )
-                q_true = jnp.where(dep, q_true - 1, q_true)
-                head_ptr = jnp.where(dep, c.head_ptr + 1, c.head_ptr)
-                # Promote the next job (if any) into service with its true size.
-                next_jid = buf_jid[jnp.arange(k), head_ptr % b]
-                next_size = sizes[jnp.clip(next_jid, 0, sizes.shape[0] - 1)]
-                head_rem = jnp.where(dep & (q_true > 0), next_size, head_rem)
-                deps = c.deps + jnp.sum(dep, dtype=jnp.int32)
-                out_t = departed_jid
+                eff_units = units
+            fifo, dep, dep_out = fifo.depart(jid, q_true, act, eff_units,
+                                             sizes)
+            q_true = jnp.where(dep, q_true - 1, q_true)
+            deps = c.deps + jnp.sum(dep, dtype=jnp.int32)
 
         # --- 3. emulation drain -----------------------------------------
         with jax.named_scope("drain"):
@@ -1160,9 +997,6 @@ def _sim_core(
             gap = jnp.max(q_true) - jnp.min(q_true)
             carry = _Carry(
                 q_true=q_true,
-                head_rem=head_rem,
-                buf_jid=buf_jid,
-                head_ptr=head_ptr,
                 emu=emu,
                 comm=comm_state,
                 rr_ptr=rr_ptr,
@@ -1179,18 +1013,13 @@ def _sim_core(
                 tokens=tokens,
                 token_miss=token_miss,
                 token_sum=token_sum,
-                last_dep=last_dep,
-                win=win,
-                cal=cal,
-                far_deps=far_deps,
+                fifo=fifo,
             )
-        return carry, out_t
+        return carry, (adm_out, dep_out)
 
+    carriage = fifo_lib.carriage(static)
     init = _Carry(
         q_true=jnp.zeros((k,), jnp.int32),
-        head_rem=None if calendar else jnp.zeros((k,), jnp.int32),
-        buf_jid=None if calendar else jnp.full((k, b), -1, jnp.int32),
-        head_ptr=None if calendar else jnp.zeros((k,), jnp.int32),
         emu=approx_lib.EmuState.init(jnp.zeros((k,), jnp.int32), acfg),
         comm=comm_lib.CommState.init(k),
         rr_ptr=jnp.zeros((), jnp.int32),
@@ -1211,89 +1040,41 @@ def _sim_core(
         tokens=jnp.zeros((k,), jnp.int32) if has_pull else None,
         token_miss=jnp.zeros((), jnp.int32) if has_pull else None,
         token_sum=jnp.zeros((), jnp.int32) if has_pull else None,
-        last_dep=jnp.full((k,), -1, jnp.int32) if calendar else None,
-        win=jnp.zeros((near * words,), jnp.uint32) if calendar else None,
-        cal=(jnp.zeros((t * words,), jnp.uint32)
-             if calendar and wraps else None),
-        far_deps=jnp.zeros((), jnp.int32) if calendar and wraps else None,
+        fifo=carriage.init(k, t, b),
     )
-    xs = (arrive, sizes, jnp.arange(t, dtype=jnp.int32), slot_keys, active)
-    if has_cls:
-        xs += (classes,)
-    if has_net:
-        xs += (net_keys,)
-    if has_fault:
-        xs += (fault_keys,)
+    xs = (arrive, sizes, jnp.arange(t, dtype=jnp.int32), slot_keys, active,
+          classes, net_keys, fault_keys)
     final, ys = jax.lax.scan(slot, init, xs)
 
-    # completion slot per job id (-1 if never completed).
-    if calendar:
-        # Each slot's job knew its completion slot when it was admitted.
-        comp_slot = ys
-    else:
-        departed = ys
-        with jax.named_scope("complete"):
-            comp_slot = jnp.full((t,), -1, jnp.int32)
-            slot_idx = jnp.broadcast_to(
-                jnp.arange(t, dtype=jnp.int32)[:, None], departed.shape
-            )
-            valid = departed >= 0
-            comp_slot = comp_slot.at[jnp.where(valid, departed, 0)].max(
-                jnp.where(valid, slot_idx, -1)
-            )
-    out = (
-        comp_slot,
-        final.comm.msgs,
-        final.deps,
-        final.arrs,
-        final.max_aq,
-        final.max_q,
-        final.per_srv,
-        final.q_true,
-        final.dropped,
-        final.gap_sup,
-        final.net.drops if has_net else jnp.zeros((), jnp.int32),
-        final.token_miss if has_pull else jnp.zeros((), jnp.int32),
-        final.token_sum if has_pull else jnp.zeros((), jnp.int32),
+    # The fields that are zeros here are outputs of every program; the
+    # None ones exist only where their kind is on.
+    return _RunOut(
+        arrive=arrive,
+        comp_slot=carriage.completions(ys),
+        msgs=final.comm.msgs,
+        deps=final.deps,
+        arrs=final.arrs,
+        max_aq=final.max_aq,
+        max_q=final.max_q,
+        per_srv=final.per_srv,
+        final_q=final.q_true,
+        dropped=final.dropped,
+        gap_sup=final.gap_sup,
+        net_drops=final.net.drops if has_net else jnp.zeros((), jnp.int32),
+        token_misses=(final.token_miss if has_pull
+                      else jnp.zeros((), jnp.int32)),
+        token_sum=final.token_sum if has_pull else jnp.zeros((), jnp.int32),
+        far_departures=final.fifo.far_departures(),
+        retrans=final.net.retrans if has_ack else None,
     )
-    # Outputs past these 13 exist only where their kind is on, so every
-    # other program keeps its output arity (byte-identical); they are
-    # named, in order, by _extra_outputs.
-    if calendar:
-        out = out + (
-            final.far_deps if wraps else jnp.zeros((), jnp.int32),
-        )
-    if has_ack:
-        out = out + (final.net.retrans,)
-    return out
-
-
-def _extra_outputs(static: StaticConfig) -> Tuple[str, ...]:
-    """The :class:`SimResult` fields of the outputs that a run's program
-    appends after the 13 every program has, in order."""
-    if static.route_backend == "pallas":
-        return ()
-    names = ("far_departures",) if _uses_calendar(static) else ()
-    if static.network != "none" and static.transport == "ack":
-        names += ("retrans",)
-    return names
 
 
 def _run_one(key, scn: Scenario, static: StaticConfig, axis_name=None):
     """Workload draw + scan for one (key, scenario) pair; vmap-able
     (``axis_name``: see :func:`_sim_core`)."""
     with jax.named_scope("draw"):
-        prep = _prep(key, static, scn)
-    arrive, sizes, slot_keys, act = prep[:4]
-    rest = list(prep[4:])
-    classes = rest.pop(0) if static.classes > 1 else None
-    net_keys = rest.pop(0) if static.network != "none" else None
-    fault_keys = rest.pop(0) if static.fault != "none" else None
-    return (arrive,) + _sim_core(
-        arrive, sizes, slot_keys, act, static, scn,
-        net_keys=net_keys, fault_keys=fault_keys, classes=classes,
-        axis_name=axis_name,
-    )
+        draws = _prep(key, static, scn)
+    return _sim_core(draws, static, scn, axis_name)
 
 
 _simulate_jit = jax.jit(_run_one, static_argnums=(2,))
@@ -1312,8 +1093,8 @@ def _grid_fn(static: StaticConfig, n_dev: int):
     (plain jitted vmap), which is also the path `shard=False` forces.
     """
     batched = jax.vmap(
-        lambda key, scn: _run_one(key, scn, static, _BATCH_AXIS),
-        axis_name=_BATCH_AXIS,
+        lambda key, scn: _run_one(key, scn, static, fifo_lib.BATCH_AXIS),
+        axis_name=fifo_lib.BATCH_AXIS,
     )
     fn = jax.jit(shard_runs(batched, n_dev, 2))
     _GRID_PROGRAMS.append(fn)
@@ -1414,7 +1195,7 @@ def _pallas_grid_fn(static: StaticConfig):
     arrival draw) builds the (N, T) arrival matrix, and a single
     ``care_route_pallas`` call advances every run as one kernel domain --
     the flattened run axis *is* the kernel's native domain axis, so no
-    vmap-of-pallas is involved.  Output tuple matches ``_run_one`` so
+    vmap-of-pallas is involved.  Its outputs are ``_run_one``'s, so
     ``_finalize``/:class:`SimResult` are shared; ``comp_slot`` is all -1
     (per-job completion tracking needs the FIFO ring the mean-field
     kernel deliberately drops, so JCT metrics are empty at this scale).
@@ -1422,9 +1203,7 @@ def _pallas_grid_fn(static: StaticConfig):
     from repro.kernels import ops as kernel_ops
 
     def run(keys, scn):
-        arrive, _sizes, _keys, _active = jax.vmap(
-            lambda k, s: _prep(k, static, s)
-        )(keys, scn)
+        arrive = jax.vmap(lambda k, s: _prep(k, static, s)[0])(keys, scn)
         params = jnp.stack(
             [
                 scn.x.astype(jnp.int32),
@@ -1444,21 +1223,22 @@ def _pallas_grid_fn(static: StaticConfig):
         )
         n, t = arrive.shape
         comp_slot = jnp.full((n, t), -1, jnp.int32)
-        return (
-            arrive,
-            comp_slot,
-            stats[:, 0],  # msgs
-            stats[:, 1],  # deps
-            stats[:, 2],  # arrs
-            stats[:, 4],  # max_aq
-            stats[:, 5],  # max_q
-            per_srv,
-            q_final,
-            stats[:, 3],  # dropped
-            stats[:, 6],  # gap_sup
-            jnp.zeros((n,), jnp.int32),  # net_drops (no network model)
-            jnp.zeros((n,), jnp.int32),  # token_misses (no pull policies)
-            jnp.zeros((n,), jnp.int32),  # token_sum
+        return _RunOut(
+            arrive=arrive,
+            comp_slot=comp_slot,
+            msgs=stats[:, 0],
+            deps=stats[:, 1],
+            arrs=stats[:, 2],
+            max_aq=stats[:, 4],
+            max_q=stats[:, 5],
+            per_srv=per_srv,
+            final_q=q_final,
+            dropped=stats[:, 3],
+            gap_sup=stats[:, 6],
+            # No network model, no pull policies.
+            net_drops=jnp.zeros((n,), jnp.int32),
+            token_misses=jnp.zeros((n,), jnp.int32),
+            token_sum=jnp.zeros((n,), jnp.int32),
         )
 
     fn = jax.jit(run)
@@ -1641,46 +1421,35 @@ def _check_control_plane(static: StaticConfig, scn: Scenario) -> None:
             raise ValueError("slow_factor must be in (0, 1]")
 
 
-def _finalize(arrive_np: np.ndarray, out, extras: Sequence[str]) -> SimResult:
-    """Convert one run's device outputs into a host-side SimResult.
-
-    ``extras`` names the outputs past the first 13
-    (:func:`_extra_outputs` of the run's static configuration).
-    """
-    out = tuple(out)
-    if len(out) != 13 + len(extras):
-        raise ValueError(
-            f"{len(out)} outputs for the 13 and {list(extras)}"
-        )
-    extra = {name: int(v) for name, v in zip(extras, out[13:])}
-    (comp_slot, msgs, deps, arrs, max_aq, max_q, per_srv, final_q, dropped,
-     gap_sup, net_drops, token_miss, token_sum) = (
-        np.asarray(o) for o in out[:13]
-    )
-
-    arrival_slots = np.nonzero(arrive_np)[0]
-    comp = comp_slot[arrival_slots]
+def _finalize(out: _RunOut) -> SimResult:
+    """Convert one run's fetched outputs into a host-side SimResult;
+    counters the run's program lacks keep their defaults."""
+    arrival_slots = np.nonzero(out.arrive)[0]
+    comp = out.comp_slot[arrival_slots]
     done = comp >= 0
     jct = comp[done] - arrival_slots[done] + 1
 
-    deps_i = int(deps)
-    msgs_i = int(msgs)
+    deps_i = int(out.deps)
+    msgs_i = int(out.msgs)
+    extra = {name: int(getattr(out, name))
+             for name in ("far_departures", "retrans")
+             if getattr(out, name) is not None}
     return SimResult(
         jct=jct.astype(np.int64),
-        arrivals=int(arrs),
+        arrivals=int(out.arrs),
         departures=deps_i,
         messages=msgs_i,
-        max_aq=int(max_aq),
-        max_queue=int(max_q),
-        overflow=bool(dropped > 0),
-        per_server_arrivals=per_srv,
-        final_q=final_q,
+        max_aq=int(out.max_aq),
+        max_queue=int(out.max_q),
+        overflow=bool(out.dropped > 0),
+        per_server_arrivals=out.per_srv,
+        final_q=out.final_q,
         msgs_per_departure=(msgs_i / deps_i) if deps_i else 0.0,
-        queue_gap_sup=int(gap_sup),
-        dropped=int(dropped),
-        net_drops=int(net_drops),
-        token_misses=int(token_miss),
-        token_sum=int(token_sum),
+        queue_gap_sup=int(out.gap_sup),
+        dropped=int(out.dropped),
+        net_drops=int(out.net_drops),
+        token_misses=int(out.token_misses),
+        token_sum=int(out.token_sum),
         **extra,
     )
 
@@ -1699,12 +1468,8 @@ def simulate(key: jax.Array, cfg: SimConfig) -> SimResult:
         out = _pallas_grid_fn(static)(
             key[None], jax.tree.map(lambda a: a[None], scn)
         )
-        return _finalize(
-            np.asarray(out[0][0]), tuple(o[0] for o in out[1:]),
-            _extra_outputs(static),
-        )
-    out = _simulate_jit(key, scn, static)
-    return _finalize(np.asarray(out[0]), out[1:], _extra_outputs(static))
+        return _finalize(jax.device_get(out).run(0))
+    return _finalize(jax.device_get(_simulate_jit(key, scn, static)))
 
 
 def grid_program(
@@ -1794,29 +1559,24 @@ def simulate_grid(
             fn, args, (c, s) = grid_program(
                 keys, static_cfg, scenarios, shard=shard
             )
-        calendar = (static_cfg.route_backend == "dense"
-                    and _uses_calendar(static_cfg))
-        root.count(runs=c * s, calendar_runs=c * s if calendar else 0)
+        root.count(runs=c * s)
         with spans.span("simulate_grid.run"):
             out = jax.block_until_ready(fn(*args))
-        with spans.span("simulate_grid.fetch",
-                        bytes=sum(o.nbytes for o in out)):
-            out_np = [np.asarray(o)[: c * s] for o in out]
-        arrive, rest = out_np[0], out_np[1:]
-        extras = _extra_outputs(static_cfg)
+        with spans.span("simulate_grid.fetch", bytes=sum(
+                o.nbytes for o in jax.tree.leaves(out))):
+            out_np = jax.tree.map(lambda o: np.asarray(o)[: c * s], out)
         with spans.span("simulate_grid.finalize") as fin:
             results = [
-                [
-                    _finalize(arrive[i * s + j],
-                              tuple(o[i * s + j] for o in rest), extras)
-                    for j in range(s)
-                ]
+                [_finalize(out_np.run(i * s + j)) for j in range(s)]
                 for i in range(c)
             ]
             fin.count(jobs=sum(r.jct.size for row in results for r in row))
-        root.count(far_departures=sum(
-            r.far_departures for row in results for r in row
-        ))
+        root.count(
+            calendar_runs=c * s if out.far_departures is not None else 0,
+            far_departures=sum(
+                r.far_departures for row in results for r in row
+            ),
+        )
     return results
 
 

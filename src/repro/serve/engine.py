@@ -1484,19 +1484,7 @@ def _serve_core(n_arr, work, tie_u, rid, sub_u, net_du, net_ju, fault_u,
     has_net = static.network != "none"
     has_fault = static.fault != "none"
     has_ack = has_net and static.transport == "ack"
-    if has_ack:
-        ncfg = comm_lib.NetworkConfig(
-            kind=static.network, delay=scn.net_delay,
-            jitter=scn.net_jitter, drop=scn.net_drop,
-            transport="ack", ack_timeout=scn.ack_timeout,
-            backoff_base=scn.backoff_base, max_retries=scn.max_retries,
-            ka_period=scn.ka_period,
-        )
-    elif has_net:
-        ncfg = comm_lib.NetworkConfig(
-            kind=static.network, delay=scn.net_delay,
-            jitter=scn.net_jitter, drop=scn.net_drop,
-        )
+    ncfg = comm_lib.network_config(static.network, static.transport, scn)
     rep_idx = jnp.arange(r_n, dtype=jnp.int32)
     # Per-replica emulated drain; msr_drain * 1.0 is exact, so the unused
     # operand cannot perturb the homogeneous path.

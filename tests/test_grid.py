@@ -29,7 +29,7 @@ import pytest
 
 from repro import spans
 from repro.core import SimConfig, simulate, simulate_batch, simulate_grid
-from repro.core.care import slotted_sim
+from repro.core.care import fifo, slotted_sim
 from repro.core.dispatch_sim import DispatchSimConfig, dispatch_batch
 from repro.core.dispatch_sim import simulate as dispatch_simulate
 
@@ -178,7 +178,7 @@ class TestSimulateGrid:
 
 ALL_K30 = tuple([True] * 30)
 # A job size that departs past the departure window even on an idle server.
-FAR_SIZE = slotted_sim.NEAR_WINDOW + 200
+FAR_SIZE = fifo.NEAR_WINDOW + 200
 CALENDAR_CFGS = {
     "geometric": SimConfig(slots=1500, load=0.95, x=3),
     "deterministic": SimConfig(slots=1500, load=0.9, service="deterministic",
@@ -208,7 +208,7 @@ CALENDAR_CFGS = {
     "far_backlog": SimConfig(slots=4000, load=0.99, servers=2,
                              mean_service=200),
     # A scan no longer than the window: the window is the whole calendar.
-    "short": SimConfig(slots=slotted_sim.NEAR_WINDOW, load=0.95),
+    "short": SimConfig(slots=fifo.NEAR_WINDOW, load=0.95),
 }
 FAR_CELLS = ("far_deterministic", "far_backlog")
 
@@ -223,8 +223,8 @@ def test_calendar_matches_ring(cell):
     ring = dataclasses.replace(
         cfg, service_rates=(1.0,) * cfg.servers, rate_aware=False
     )
-    assert slotted_sim._uses_calendar(cfg.static_part())
-    assert not slotted_sim._uses_calendar(ring.static_part())
+    assert fifo.carriage(cfg.static_part()) is fifo.Calendar
+    assert fifo.carriage(ring.static_part()) is fifo.JobRing
     a = simulate(jax.random.key(5), cfg)
     b = simulate(jax.random.key(5), ring)
     assert a.departures > 0
@@ -238,7 +238,7 @@ def test_calendar_matches_ring(cell):
     assert b.far_departures == 0
     if cell in FAR_CELLS:
         assert a.far_departures > 0
-    if cfg.static_part().slots <= slotted_sim.NEAR_WINDOW:
+    if cfg.static_part().slots <= fifo.NEAR_WINDOW:
         assert a.far_departures == 0
 
 
@@ -279,7 +279,7 @@ class TestGridSpans:
         fn, args, _ = slotted_sim.grid_program(list(GRID_SEEDS), static, scns)
         out_bytes = sum(
             int(np.prod(o.shape)) * o.dtype.itemsize
-            for o in jax.eval_shape(fn, *args)
+            for o in jax.tree.leaves(jax.eval_shape(fn, *args))
         )
         return grid, spans.last("simulate_grid"), out_bytes
 
